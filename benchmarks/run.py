@@ -31,6 +31,10 @@ def main(argv=None):
     args = ap.parse_args(argv)
     mc = 64 if args.fast else None
 
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     from . import (
         fig1_numerical,
         fig1_testbed,

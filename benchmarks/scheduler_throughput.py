@@ -52,7 +52,7 @@ from repro.core import (
     hier_backend_fn,
     hier_cells_np,
 )
-from repro.kernels.gus_pallas import gus_pallas_interpret_default
+from repro.kernels.gus_pallas import pallas_interpret
 from repro.kernels.hier_pallas import hier_cells_pallas
 
 from .common import csv_row, gate_rows_against_baseline
@@ -77,7 +77,7 @@ def _env_meta() -> dict:
         "device_platform": dev.platform,
         "device_kind": dev.device_kind,
         "n_devices": jax.local_device_count(),
-        "pallas_interpret": gus_pallas_interpret_default(),
+        "pallas_interpret": pallas_interpret(),
     }
 
 

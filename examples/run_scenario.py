@@ -38,6 +38,7 @@ from repro.core import (
     simulate,
     simulate_fleet,
 )
+from repro.compile_cache import use_compile_cache
 from repro.obs import (
     AsyncJsonlWriter,
     profile_trace,
@@ -115,6 +116,7 @@ def main(argv=None):
     ap.add_argument("--list", action="store_true",
                     help="list scenarios and policies, then exit")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if not args.fleet and (
         args.devices is not None or args.window is not None

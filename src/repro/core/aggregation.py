@@ -62,12 +62,16 @@ request index*, ``(take, start)`` are ``(C, M, L)`` int32 tensors where
 and ``start[c, j, l]`` is their offset into the class's (ascending)
 member list.  Consecutive re-picks of one cell accumulate, so member
 ranges stay contiguous and :func:`deaggregate` semantics are preserved.
-The chunk sizing is float32 with one explicit IEEE op sequence —
-``floor(budget / cost)``, ``min`` against the remainder, ``budget -
-take * cost`` — shared verbatim by the NumPy oracle
+The chunk sizing is float32 with one explicit IEEE op sequence — the
+fit count ``max{t : f32(t * cost) <= budget}``, ``min`` against the
+remainder, ``budget - take * cost`` — shared verbatim by the NumPy oracle
 (:func:`hier_cells_np`), the XLA scan and the Pallas kernel, which is
 what makes three-way bit-parity (``tests/test_hier_parity.py``)
-well-defined with jax's default float32 everywhere.
+well-defined with jax's default float32 everywhere.  The fit count starts
+from ``floor(budget / cost)`` and corrects it by one step each way with
+f32 multiplies: a TPU's f32 divide is not correctly rounded, and on an
+exact multiple the bare floor lands one lower than NumPy's, while the
+corrected count depends only on correctly rounded products.
 """
 from __future__ import annotations
 
@@ -79,6 +83,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels.gus_pallas import pallas_interpret
+from repro.kernels.hier_pallas import fit_count, hier_cells_pallas
 
 from .gus import Assignment, resolve_gus_backend
 from .instance import FlatInstance
@@ -442,6 +449,18 @@ def hier_assign(
     return np.asarray(chunks, np.int64)
 
 
+def _fit_count_np(budget, cost):
+    """``max{t : f32(t * cost) <= budget}`` for f32 scalars, ``cost > 0`` —
+    the NumPy twin of :func:`repro.kernels.hier_pallas.fit_count`."""
+    one = np.float32(1.0)
+    q = np.floor(budget / cost)
+    if q * cost > budget:
+        q = q - one
+    if (q + one) * cost <= budget:
+        q = q + one
+    return q
+
+
 def hier_cells_np(
     us: np.ndarray,
     feas: np.ndarray,
@@ -458,8 +477,9 @@ def hier_cells_np(
     ``first_idx``); ``(take, start)`` are the fixed-shape cell tensors
     described in the module docstring.  All capacity arithmetic is float32
     with the exact op sequence of the XLA scan and the Pallas kernel:
-    ``cap = floor(budget / cost)`` (f32 divide then f32 floor), ``take =
-    min(rem, cap_gamma, cap_eta)``, ``budget -= f32(take) * cost``.
+    ``cap`` = the fit count ``max{t : f32(t * cost) <= budget}``
+    (:func:`_fit_count_np`), ``take = min(rem, cap_gamma, cap_eta)``,
+    ``budget -= f32(take) * cost``.
     Zero-count rows (padding) and classes with no feasible cell are
     skipped without touching the budgets.
 
@@ -496,9 +516,9 @@ def hier_cells_np(
             uv = u[c, j, l]
             t_f = np.float32(rem)
             if vv > 0:
-                t_f = min(t_f, np.floor(gamma[j] / vv))
+                t_f = min(t_f, _fit_count_np(gamma[j], vv))
             if j != s and uv > 0:
-                t_f = min(t_f, np.floor(eta[s] / uv))
+                t_f = min(t_f, _fit_count_np(eta[s], uv))
             t = int(t_f)
             if t < 1:
                 break  # float edge: cell passed ``ok`` but fits zero members
@@ -560,11 +580,11 @@ def _hier_cells_xla(us, feas, v, u, cover, count, gamma, eta):
             offl = j != s
             rem_f = rem.astype(jnp.float32)
             cap_g = jnp.where(
-                vv > 0, jnp.floor(gamma[j] / jnp.where(vv > 0, vv, 1.0)), rem_f
+                vv > 0, fit_count(gamma[j], jnp.where(vv > 0, vv, 1.0)), rem_f
             )
             cap_e = jnp.where(
                 offl & (uv > 0),
-                jnp.floor(eta[s] / jnp.where(uv > 0, uv, 1.0)),
+                fit_count(eta[s], jnp.where(uv > 0, uv, 1.0)),
                 rem_f,
             )
             t_f = jnp.minimum(rem_f, jnp.minimum(cap_g, cap_e))
@@ -604,14 +624,11 @@ def _hier_cells_pallas(us, feas, v, u, cover, count, gamma, eta):
     """Fused-Pallas entry: batch-of-1 lift into the hierarchical kernel
     (``vmap`` over the fleet's replication axis lifts it further, exactly
     like the dense GUS kernel).  The interpret flag resolves at trace
-    time, same env switch as the dense kernel."""
-    from repro.kernels.gus_pallas import gus_pallas_interpret_default
-    from repro.kernels.hier_pallas import hier_cells_pallas
-
+    time from the platform, as for the dense kernel."""
     add = lambda x: jnp.asarray(x)[None]  # noqa: E731 — lift to batch of 1
     take, start = hier_cells_pallas(
         add(us), add(feas), add(v), add(u), add(cover), add(count),
-        add(gamma), add(eta), interpret=gus_pallas_interpret_default(),
+        add(gamma), add(eta), interpret=pallas_interpret(),
     )
     return take[0], start[0]
 

@@ -11,8 +11,9 @@ Three implementations behind one dispatcher:
 * ``backend="pallas"`` — the fused Pallas kernel
   (:mod:`repro.kernels.gus_pallas`): utility computation, feasibility and the
   greedy capacity loop in one on-chip program, one grid step per frame in the
-  batch.  Compiled Mosaic on TPU; ``interpret=True`` (plain jax ops) on CPU,
-  which is how CI validates it.
+  batch.  Compiled Mosaic on TPU; interpret mode (plain jax ops) on CPU,
+  which is how CI validates it
+  (:func:`repro.kernels.gus_pallas.pallas_interpret` decides).
 
 All three return ``Assignment(j, l)`` with j = l = -1 encoding *drop* and are
 held to **bit-identical** assignments on the same frame — integer outputs, so
@@ -38,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.gus_pallas import gus_assign_pallas
+from repro.kernels.gus_pallas import gus_assign_pallas, pallas_interpret
 from repro.obs.profiler import annotate
 
 from .instance import FlatInstance
@@ -207,7 +208,7 @@ def _gus_schedule_pallas(
     *,
     relax_compute: bool = False,
     relax_comm: bool = False,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Assignment:
     """Single-frame entry to the fused Pallas kernel (batch of one grid
     program; ``vmap`` lifts it to one program per batched frame)."""
@@ -228,7 +229,7 @@ def _gus_schedule_batch_pallas(
     *,
     relax_compute: bool = False,
     relax_comm: bool = False,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Assignment:
     """Natively-batched Pallas entry: grid = the leading batch axis, one
     grid program per frame — no vmap lifting."""
@@ -240,12 +241,6 @@ def _gus_schedule_batch_pallas(
         interpret=interpret,
     )
     return Assignment(j, l)
-
-
-def _pallas_interpret() -> bool:
-    from repro.kernels.gus_pallas import gus_pallas_interpret_default
-
-    return gus_pallas_interpret_default()
 
 
 def gus_schedule(
@@ -265,7 +260,7 @@ def gus_schedule(
         with annotate("gus/pallas_kernel"):
             return _gus_schedule_pallas(
                 inst, relax_compute=relax_compute, relax_comm=relax_comm,
-                interpret=_pallas_interpret(),
+                interpret=pallas_interpret(),
             )
     with annotate("gus/xla"):
         return _gus_schedule_xla(
@@ -297,7 +292,7 @@ def gus_schedule_batch(
         with annotate("gus/pallas_kernel_batch"):
             return _gus_schedule_batch_pallas(
                 batch, relax_compute=relax_compute, relax_comm=relax_comm,
-                interpret=_pallas_interpret(),
+                interpret=pallas_interpret(),
             )
     with annotate("gus/xla_batch"):
         return _gus_schedule_batch_xla(

@@ -7,6 +7,11 @@ CPU against the pure-jnp oracles in ref.py):
   gus_pallas       — fused GUS greedy-assignment kernel (utility + feasibility
                      + capacity-aware argmax loop), bit-parity-tested against
                      the NumPy and XLA schedulers in repro.core.gus
+  hier_pallas      — fused hierarchical class allocator, parity-tested
+                     against repro.core.aggregation.hier_cells_np
+
+On a TPU the scheduler kernels compile (Mosaic); on the CPU they run in
+interpret mode (gus_pallas.pallas_interpret decides from the platform).
 """
 from . import ops, ref
 from .flash_attention import flash_attention as flash_attention_kernel

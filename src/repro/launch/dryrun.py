@@ -1,6 +1,5 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_dryrun_cache")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "10")
 """Multi-pod dry-run (deliverable (e)).
 
@@ -22,6 +21,7 @@ import time
 import traceback
 
 
+from ..compile_cache import use_compile_cache
 from ..configs import ARCH_IDS, get_config
 from ..models.model import Model
 from ..roofline import roofline_terms
@@ -158,6 +158,7 @@ def main(argv=None):
              "flop undercount (use for multi-pod lowering-only passes)",
     )
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     archs = ARCH_IDS if args.arch == "all" else [args.arch]
     shapes = list(SHAPES) if args.shape == "all" else [args.shape]

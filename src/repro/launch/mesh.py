@@ -8,17 +8,13 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_test_mesh", "make_fleet_mesh", "mesh_name"]
 
 
 def _make_mesh(shape, axes):
-    # jax >= 0.5 takes axis_types (and 0.7+ defaults to Explicit); jax 0.4.x
-    # has no jax.sharding.AxisType — its meshes are always Auto.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

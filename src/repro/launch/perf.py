@@ -1,6 +1,5 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_dryrun_cache")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "10")
 """Perf-iteration harness (§Perf): lower one (arch x shape) under a NAMED
 experiment variant (sharding-rule override and/or config tweak), emit the
@@ -17,6 +16,7 @@ import dataclasses
 import sys
 import time
 
+from ..compile_cache import use_compile_cache
 from ..configs import ARCH_IDS, get_config
 from ..sharding import DEFAULT_RULES
 from .dryrun import lower_one
@@ -168,6 +168,7 @@ def main(argv=None):
     ap.add_argument("--variant", choices=list(VARIANTS), action="append", required=True)
     ap.add_argument("--out", default="reports/perf")
     args = ap.parse_args(argv)
+    use_compile_cache()
     for v in args.variant:
         run_variant(args.arch, args.shape, v, args.out)
     return 0

@@ -8,13 +8,11 @@ scheduler call) with ``jax.profiler.TraceAnnotation`` and
 
 When no profile is active — the default — both helpers return one shared
 ``nullcontext`` instance, so instrumented call sites cost a function
-call and a flag check.  A host platform without profiler support (or a
-jax build that cannot start one) degrades to a warning, never an error:
-profiling is observability, not a dependency.
+call and a flag check.  A profile that was asked for and cannot start or
+stop raises: a run told to trace never finishes without its trace.
 """
 from __future__ import annotations
 
-import warnings
 from contextlib import contextmanager, nullcontext
 
 import jax
@@ -35,27 +33,20 @@ def profile_trace(log_dir):
 
     ``log_dir`` of ``None``/empty yields without starting anything, so
     callers can thread an optional ``--profile DIR`` flag straight
-    through.
+    through.  A trace that cannot start or stop raises — the exception
+    from ``jax.profiler`` propagates.
     """
     global _ACTIVE
     if not log_dir:
         yield
         return
-    try:
-        jax.profiler.start_trace(str(log_dir))
-    except Exception as e:  # no profiler backend on this host
-        warnings.warn(f"jax profiler unavailable ({e}); running unprofiled")
-        yield
-        return
+    jax.profiler.start_trace(str(log_dir))
     _ACTIVE = True
     try:
         yield
     finally:
         _ACTIVE = False
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:
-            warnings.warn(f"jax profiler stop failed ({e})")
+        jax.profiler.stop_trace()
 
 
 def annotate(name: str, **kwargs):

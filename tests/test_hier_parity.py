@@ -558,3 +558,45 @@ def test_member_jitter_golden_fixture():
         g["satisfied_per_rep"], np.asarray(fr.satisfied_per_rep))
     np.testing.assert_allclose(
         g["mean_us_per_rep"], np.asarray(fr.mean_us_per_rep), rtol=1e-6)
+
+
+def test_class_chunks_carry_budgets_across_grid_steps(monkeypatch):
+    """A frame whose classes span several grid steps (and a padded tail)
+    allocates exactly like the oracle: the budget carry survives from one
+    class chunk to the next and restarts for the next frame."""
+    from repro.kernels import hier_pallas
+
+    monkeypatch.setattr(hier_pallas, "CLASS_CHUNK", 8)
+    frames = []
+    for seed in (0, 1):
+        inst = tile_instance(generate_instance(seed, SMALL, as_numpy=True), 2)
+        agg = aggregate_instance(inst)
+        frames.append(_class_args(agg, np.asarray(inst.gamma),
+                                  np.asarray(inst.eta), pad_to=37))
+    batch = [np.stack(xs) for xs in zip(*frames)]
+    take, start = hier_pallas.hier_cells_pallas(*batch, interpret=True)
+    for b, args in enumerate(frames):
+        want_take, want_start = hier_cells_np(*args)
+        assert want_take.sum() > 0
+        np.testing.assert_array_equal(np.asarray(take[b]), want_take)
+        np.testing.assert_array_equal(np.asarray(start[b]), want_start)
+
+
+def test_fit_count_is_largest_fitting_multiple():
+    """The chunk count every backend shares is ``max{t : f32(t * cost) <=
+    budget}``, on exact multiples (where a chip's inexact divide floors one
+    low) and on arbitrary budgets alike."""
+    from repro.core.aggregation import _fit_count_np
+    from repro.kernels.hier_pallas import fit_count
+
+    rng = np.random.default_rng(0)
+    cost = rng.uniform(0.5, 3000.0, 4000).astype(np.float32)
+    k = rng.integers(1, 64, 4000).astype(np.float32)
+    budgets = np.concatenate([k * cost, rng.uniform(0, 20000, 4000).astype(np.float32)])
+    costs = np.concatenate([cost, cost])
+    got = np.asarray(jax.jit(fit_count)(budgets, costs))
+    for b, c, g in zip(budgets, costs, got):
+        t = np.floor(np.float64(b) / np.float64(c)) + 1
+        while np.float32(np.float32(t) * c) > b:
+            t -= 1
+        assert g == t == _fit_count_np(b, c), (b, c, g, t)

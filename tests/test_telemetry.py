@@ -351,3 +351,25 @@ def test_run_scenario_metrics_and_trace(tmp_path, monkeypatch):
     rows = [json.loads(line) for line in out.read_text().splitlines()]
     assert sum(row["n_satisfied"] for row in rows) == r.n_satisfied
     assert sum(row["n_arrivals"] for row in rows) == r.n_requests
+
+
+@pytest.mark.parametrize("failing", ["start_trace", "stop_trace"])
+def test_profile_trace_raises_when_trace_fails(tmp_path, monkeypatch, failing):
+    """A requested profile that cannot start or stop is an error: the run
+    never exits as if it had been traced."""
+    import jax
+
+    from repro.obs import profile_trace, profiling_active
+
+    def boom(*a, **k):
+        raise RuntimeError(f"{failing} unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, failing, boom)
+    with pytest.raises(RuntimeError, match=failing):
+        with profile_trace(tmp_path / "prof"):
+            pass
+    assert not profiling_active()
+    with profile_trace(None):  # no profile asked for: nothing starts
+        assert not profiling_active()
