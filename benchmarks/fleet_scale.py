@@ -442,8 +442,7 @@ def main(argv=None):
                     help="also write the report to PATH (refresh the baseline)")
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="capture a jax.profiler device trace of the sweep "
-                         "into DIR (fleet dispatch groups and scan windows "
-                         "are annotated)")
+                         "into DIR (the program's spans are annotated)")
     args = ap.parse_args(argv)
 
     repeats = args.repeats if args.repeats is not None else (2 if args.tiny else 3)

@@ -5,10 +5,10 @@ Measures, at the fleet benchmark's 64-replication point (the same
 
 1. **Disabled-path overhead** — the cost the span instrumentation adds to
    a run with no recorder installed.  The per-span disabled cost (two
-   ``perf_counter`` calls + a ``None`` check) is microbenchmarked
-   directly, the span count of the bench point is taken from a recorded
-   run, and their product over the untraced wall time is the overhead
-   fraction.  This analytic form is robust to run-to-run noise that
+   ``perf_counter`` calls, a ``None`` check and a profile-flag check) is
+   microbenchmarked directly, the span count of the bench point is taken
+   from a recorded run, and their product over the untraced wall time is
+   the overhead fraction.  This analytic form is robust to run-to-run noise that
    would swamp a naive wall-clock diff of two sub-second runs; the gate
    (``--assert-overhead``, CI uses 0.01) holds it under 1%.
 2. **Enabled overheads** — wall-clock deltas of (a) recording host spans

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.gus_pallas import gus_assign_pallas, pallas_interpret
-from repro.obs.profiler import annotate
+from repro.obs.trace import CAT_SCHED, span
 
 from .instance import FlatInstance
 from .satisfaction import hard_feasible, us_tensor
@@ -256,13 +256,13 @@ def gus_schedule(
     loop, ``"pallas"`` fused kernel; ``None`` defers to the
     ``REPRO_GUS_BACKEND`` environment variable) — assignments are
     bit-identical across backends."""
-    if resolve_gus_backend(backend) == "pallas":
-        with annotate("gus/pallas_kernel"):
+    backend = resolve_gus_backend(backend)
+    with span("gus/call", CAT_SCHED, backend=backend):
+        if backend == "pallas":
             return _gus_schedule_pallas(
                 inst, relax_compute=relax_compute, relax_comm=relax_comm,
                 interpret=pallas_interpret(),
             )
-    with annotate("gus/xla"):
         return _gus_schedule_xla(
             inst, relax_compute=relax_compute, relax_comm=relax_comm
         )
@@ -288,13 +288,13 @@ def gus_schedule_batch(
     """GUS over a leading instance-batch axis (Monte-Carlo runs): vmapped
     XLA by default, or the natively-batched Pallas kernel (one grid program
     per frame) with ``backend="pallas"``."""
-    if resolve_gus_backend(backend) == "pallas":
-        with annotate("gus/pallas_kernel_batch"):
+    backend = resolve_gus_backend(backend)
+    with span("gus/call_batch", CAT_SCHED, backend=backend):
+        if backend == "pallas":
             return _gus_schedule_batch_pallas(
                 batch, relax_compute=relax_compute, relax_comm=relax_comm,
                 interpret=pallas_interpret(),
             )
-    with annotate("gus/xla_batch"):
         return _gus_schedule_batch_xla(
             batch, relax_compute=relax_compute, relax_comm=relax_comm
         )

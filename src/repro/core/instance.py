@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import CAT_BUILD, span
+
 __all__ = [
     "FlatInstance",
     "GeneratorConfig",
@@ -287,22 +289,23 @@ def pad_instance(inst: FlatInstance, n_pad: int) -> FlatInstance:
         x = jnp.asarray(x)
         return jnp.concatenate([x, jnp.full((p,) + x.shape[1:], fill, x.dtype)])
 
-    return FlatInstance(
-        cover=_pad(inst.cover, 0),
-        A=_pad(inst.A, 1e9),        # unreachable accuracy floor
-        C=_pad(inst.C, -1.0),       # already-expired deadline
-        w_a=_pad(inst.w_a, 0.0),    # padded rows contribute zero US
-        w_c=_pad(inst.w_c, 0.0),
-        acc=_pad(inst.acc, 0.0),
-        ctime=_pad(inst.ctime, 1e9),
-        v=_pad(inst.v, 0.0),
-        u=_pad(inst.u, 0.0),
-        avail=_pad(inst.avail, False),
-        gamma=inst.gamma,
-        eta=inst.eta,
-        max_as=inst.max_as,
-        max_cs=inst.max_cs,
-    )
+    with span("gus/pad", CAT_BUILD, n=N, n_pad=n_pad):
+        return FlatInstance(
+            cover=_pad(inst.cover, 0),
+            A=_pad(inst.A, 1e9),        # unreachable accuracy floor
+            C=_pad(inst.C, -1.0),       # already-expired deadline
+            w_a=_pad(inst.w_a, 0.0),    # padded rows contribute zero US
+            w_c=_pad(inst.w_c, 0.0),
+            acc=_pad(inst.acc, 0.0),
+            ctime=_pad(inst.ctime, 1e9),
+            v=_pad(inst.v, 0.0),
+            u=_pad(inst.u, 0.0),
+            avail=_pad(inst.avail, False),
+            gamma=inst.gamma,
+            eta=inst.eta,
+            max_as=inst.max_as,
+            max_cs=inst.max_cs,
+        )
 
 
 def generate_batch(seed: int, n: int, cfg: Optional[GeneratorConfig] = None):

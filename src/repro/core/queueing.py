@@ -60,6 +60,7 @@ __all__ = [
     "ema_update",
     "effective_capacity",
     "congested_ctime",
+    "CONGESTED_CTIME_LEAVES",
     "frame_utilization",
     "frame_metrics",
 ]
@@ -237,6 +238,10 @@ def ema_update(ema, committed, budget, cfg: CongestionConfig):
     """EMA of per-server committed utilization (``committed / budget``)."""
     util = committed / jnp.maximum(budget, _EPS)
     return (1.0 - cfg.ema_alpha) * ema + cfg.ema_alpha * util
+
+
+#: the ``FlatInstance`` leaves :func:`congested_ctime` reads
+CONGESTED_CTIME_LEAVES = ("ctime", "v", "cover")
 
 
 def congested_ctime(inst: FlatInstance, tq, phi_c, phi_e) -> jnp.ndarray:

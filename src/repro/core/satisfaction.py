@@ -11,7 +11,11 @@ import jax.numpy as jnp
 
 from .instance import FlatInstance
 
-__all__ = ["us_tensor", "hard_feasible", "mean_us", "satisfied_mask"]
+__all__ = ["us_tensor", "hard_feasible", "mean_us", "satisfied_mask", "SATISFACTION_LEAVES"]
+
+#: the ``FlatInstance`` leaves :func:`satisfied_mask` and :func:`mean_us`
+#: read; a caller that uploads their inputs itself uploads these
+SATISFACTION_LEAVES = ("acc", "ctime", "A", "C", "w_a", "w_c", "max_as", "max_cs")
 
 
 def us_tensor(inst: FlatInstance) -> jnp.ndarray:

@@ -66,7 +66,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs.metrics import QOS_ACC_EDGES, MetricsFrame, MetricsResult
-from repro.obs.profiler import annotate, step_annotation
 from repro.obs.trace import (
     CAT_BUILD,
     CAT_COMPILE,
@@ -74,6 +73,7 @@ from repro.obs.trace import (
     CAT_GEN,
     CAT_METRICS,
     CAT_SCHED,
+    CAT_WAIT,
     Stopwatch,
     instant,
     span,
@@ -97,6 +97,7 @@ from .options import (
 )
 from .policies import Policy, get_policy
 from .queueing import (
+    CONGESTED_CTIME_LEAVES,
     CongestionConfig,
     comm_inflation,
     committed_loads,
@@ -109,7 +110,13 @@ from .queueing import (
     init_policy_carry,
     step_backlog,
 )
-from .satisfaction import hard_feasible, mean_us, satisfied_mask, us_tensor
+from .satisfaction import (
+    SATISFACTION_LEAVES,
+    hard_feasible,
+    mean_us,
+    satisfied_mask,
+    us_tensor,
+)
 from .scenarios import (
     Request,
     RequestColumns,
@@ -1049,8 +1056,7 @@ def simulate(
             # compile once per bucket; padded rows are infeasible -> dropped.
             # Non-padding policies (the ILP oracle) see the raw frame.
             frame_inst = pad_instance(inst, _pad_bucket(n_real)) if pad else inst
-            with sw.span("sim/schedule", CAT_SCHED, n=n_real), \
-                    annotate("sim/schedule"):
+            with sw.span("sim/schedule", CAT_SCHED, n=n_real):
                 if stateful:
                     assign, carry = scheduler(frame_inst, carry)
                 elif needs_key:
@@ -1299,10 +1305,13 @@ class FleetResult:
     gen_s: float = 0.0
     #: producer-queue depth the run used (0 = serial single-thread build)
     prefetch: int = 0
-    #: per-span wall-clock totals from the run's :class:`~repro.obs.trace.
-    #: Stopwatch` — ``gen_s``/``dispatch_s`` above are derived from these
-    #: same spans, so the two views can never disagree
-    timings: Optional[Dict[str, float]] = None
+    #: per-span wall-clock totals (float seconds) from the run's
+    #: :class:`~repro.obs.trace.Stopwatch` — ``gen_s``/``dispatch_s`` above
+    #: are derived from these same spans, so the two views can never
+    #: disagree — plus its counters, which are integer counts, not seconds:
+    #: keys ending ``_bytes`` (``fleet/h2d_bytes``,
+    #: ``fleet/accounting_h2d_bytes``) and ``/rows`` (``fleet/rows``)
+    timings: Optional[Dict[str, Union[float, int]]] = None
     #: per-(rep, frame) metric stream (``metrics=True`` only; None otherwise)
     metrics: Optional[MetricsResult] = None
 
@@ -1865,12 +1874,7 @@ def simulate_fleet(
 
         group_devices = list(make_fleet_mesh(n_dev).devices.ravel())
     else:
-        group_devices = [None]  # default device, no explicit placement
-
-    def to_device(tree, dev):
-        if dev is None:
-            return tree
-        return jax.tree.map(lambda x: jax.device_put(x, dev), tree)
+        group_devices = [None]  # jax.device_put's default device
 
     # worker threads drive the devices concurrently (XLA releases the GIL
     # during execution); more workers than physical cores only adds
@@ -1882,7 +1886,7 @@ def simulate_fleet(
         carry = _pad_reps(carry, pad_r)
         keys_all = _pad_reps(keys_all, pad_r)
     carries = [
-        to_device(
+        jax.device_put(
             jax.tree.map(lambda x: x[g * G:(g + 1) * G], carry),
             group_devices[g % n_dev],
         )
@@ -2013,66 +2017,82 @@ def simulate_fleet(
     m_acc: Optional[Dict[str, np.ndarray]] = None
     try:
         for wi, wi_t0 in enumerate(window_starts):
-            with sw.span("fleet/window_wait", CAT_GEN, window=wi):
+            with sw.span("fleet/window_wait", CAT_WAIT, window=wi):
                 (t0, t1, Tc, batch, batch_rt, n_real, tq_flat,
                  tq_rt, link_rt, up_rt, nreal_rt) = next_window(wi_t0)
             keys_rt = keys_all[:, t0:t1]
 
             def run_group(g):
                 sl = slice(g * G, (g + 1) * G)
-                dev = group_devices[g % n_dev]
-                argv = [
-                    carries[g],
-                    to_device(jax.tree.map(lambda x: x[sl], batch_rt), dev),
-                    to_device(keys_rt[sl], dev),
-                    to_device(tq_rt[sl], dev),
-                    to_device(np.ascontiguousarray(link_rt[sl]), dev),
-                    to_device(np.ascontiguousarray(up_rt[sl]), dev),
+                host = [
+                    jax.tree.map(lambda x: x[sl], batch_rt),
+                    keys_rt[sl],
+                    tq_rt[sl],
+                    np.ascontiguousarray(link_rt[sl]),
+                    np.ascontiguousarray(up_rt[sl]),
                 ]
                 if metrics:
-                    argv.append(to_device(nreal_rt[sl], dev))
-                with annotate(f"fleet/group{g}"):
-                    c, out = run(*argv)
-                    # materialize here (XLA releases the GIL while computing,
-                    # so worker threads overlap groups across devices); the
-                    # carry stays device-resident for the next window
+                    host.append(nreal_rt[sl])
+                # explicit host->device placement, on the group's device
+                # (the default one when single-device)
+                with sw.span("fleet/put", CAT_DISPATCH, group=g):
+                    argv = jax.device_put(host, group_devices[g % n_dev])
+                sw.count("fleet/h2d_bytes",
+                         sum(x.nbytes for x in jax.tree.leaves(host)))
+                c, out = run(carries[g], *argv)
+                # materialize here (XLA releases the GIL while computing,
+                # so worker threads overlap groups across devices); the
+                # carry stays device-resident for the next window
+                with sw.span("fleet/drain", CAT_DISPATCH, group=g):
                     return c, jax.tree.map(np.asarray, out)
 
             with sw.span(
-                "fleet/dispatch", CAT_DISPATCH, window=wi, n_groups=n_groups
-            ), step_annotation("fleet/window", wi):
+                "fleet/dispatch", CAT_DISPATCH, step=wi, window=wi, n_groups=n_groups
+            ):
                 if executor is None:
                     results = [run_group(g) for g in range(n_groups)]
                 else:
                     results = list(executor.map(run_group, range(n_groups)))
             for g, (c, _) in enumerate(results):
                 carries[g] = c
+            sw.count("fleet/rows", n_rep * Tc * n_pad)
             with sw.span("fleet/window_metrics", CAT_METRICS, window=wi):
                 jv, lv, pc, pe = (
                     np.concatenate([r[1][part] for r in results])[:n_rep]
                     for part in range(4)
                 )
-                assign = Assignment(
-                    jnp.asarray(jv.reshape(n_rep * Tc, n_pad)),
-                    jnp.asarray(lv.reshape(n_rep * Tc, n_pad)),
+                # one explicit upload of every host array the device
+                # accounting below reads
+                leaves = dict.fromkeys(
+                    SATISFACTION_LEAVES
+                    + (CONGESTED_CTIME_LEAVES if ccfg.enabled else ())
                 )
+                host = {f: getattr(batch, f) for f in leaves}
+                host["j"] = jv.reshape(n_rep * Tc, n_pad)
+                host["l"] = lv.reshape(n_rep * Tc, n_pad)
                 if ccfg.enabled:
-                    phi_c = jnp.asarray(pc.reshape(n_rep * Tc, M))
-                    phi_e = jnp.asarray(pe.reshape(n_rep * Tc, M))
+                    host.update(
+                        tq=tq_flat,
+                        phi_c=pc.reshape(n_rep * Tc, M),
+                        phi_e=pe.reshape(n_rep * Tc, M),
+                    )
+                sw.count("fleet/accounting_h2d_bytes",
+                         sum(x.nbytes for x in host.values()))
+                dev = jax.device_put(host)
+                mbatch = dataclasses.replace(batch, **{f: dev[f] for f in leaves})
+                if ccfg.enabled:
                     mbatch = dataclasses.replace(
-                        batch,
+                        mbatch,
                         ctime=congested_ctime(
-                            batch, jnp.asarray(tq_flat), phi_c, phi_e
+                            mbatch, dev["tq"], dev["phi_c"], dev["phi_e"]
                         ),
                     )
                     phi_frames[:, t0:t1] = pc
-                else:
-                    mbatch = batch
 
-                sat = np.asarray(satisfied_mask(mbatch, assign.j, assign.l))
-                us = np.asarray(mean_us(mbatch, assign.j, assign.l))
+                sat = np.asarray(satisfied_mask(mbatch, dev["j"], dev["l"]))
+                us = np.asarray(mean_us(mbatch, dev["j"], dev["l"]))
                 real = np.arange(n_pad)[None, :] < n_real.reshape(-1)[:, None]
-                served = (np.asarray(assign.j) >= 0) & real
+                served = (host["j"] >= 0) & real
                 sat = sat & real
                 sat_frames[:, t0:t1] = sat.sum(-1).reshape(n_rep, Tc)
                 served_frames[:, t0:t1] = served.sum(-1).reshape(n_rep, Tc)
@@ -2871,9 +2891,10 @@ def _simulate_fleet_hier(
             the next window's computation has already been issued — keeps
             the device busy while the host deaggregates members."""
             nonlocal phi_sum, phi_cnt, reqs_per_rep
-            with sw.span("fleet/hier_post", CAT_METRICS, window=wi):
+            with sw.span("fleet/hier_drain", CAT_DISPATCH, window=wi):
                 (take_a, start_a, pc_a, pe_a, w_a_, c_a, shed_a, ref_a,
                  bg_a, be_a) = jax.tree.map(np.asarray, outs)
+            with sw.span("fleet/hier_deaggregate", CAT_METRICS, window=wi):
                 if ccfg.enabled:
                     phi_sum += float(pc_a.sum())
                     phi_cnt += pc_a.size
@@ -2978,12 +2999,10 @@ def _simulate_fleet_hier(
 
         pending = None
         for wi, wi_t0 in enumerate(window_starts):
-            with sw.span("fleet/window_wait", CAT_GEN, window=wi):
+            with sw.span("fleet/window_wait", CAT_WAIT, window=wi):
                 (t0, t1, Tc, batch_rt, us_rt, feas_rt, tq_rt, cnt_rt, infos,
                  gb, eb, links_by_k, n_arr) = next_window(wi_t0)
-            with sw.span(
-                "fleet/dispatch", CAT_DISPATCH, window=wi
-            ), step_annotation("fleet/hier_window", wi):
+            with sw.span("fleet/dispatch", CAT_DISPATCH, step=wi, window=wi):
                 carry, outs = run(
                     carry, batch_rt, us_rt, feas_rt, tq_rt, cnt_rt
                 )

@@ -3,12 +3,14 @@
 Three layers, all bitwise-inert when disabled (the same discipline as the
 congestion and impairment engines — see ``docs/architecture.md`` §10):
 
-* :mod:`repro.obs.trace` — host-side span tracing.  ``span("name")``
-  context managers feed a process-wide :class:`TraceRecorder` that emits
-  Chrome trace-event JSON (loadable in ``chrome://tracing`` / Perfetto),
-  with correct thread attribution for the fleet's producer thread and the
-  async JSONL exporter.  With no recorder installed a span is two
-  ``perf_counter`` calls and nothing else.
+* :mod:`repro.obs.trace` — the program's one instrument.  A
+  ``span("name")`` block feeds a run's :class:`Stopwatch` (timing fields
+  and counters), a process-wide :class:`TraceRecorder` that emits Chrome
+  trace-event JSON (loadable in ``chrome://tracing`` / Perfetto, with
+  correct thread attribution for the fleet's producer thread and the
+  async JSONL exporter), and, under an active profile, the profiler trace
+  as an annotation (waits excepted).  With neither installed a span is
+  two ``perf_counter`` calls and two checks.
 * :mod:`repro.obs.metrics` — per-frame metric streams.  The simulators'
   opt-in ``metrics=True`` path emits one :class:`MetricsFrame` per frame
   (per-server utilization/backlog, admission sheds, per-QoS-class
@@ -17,10 +19,8 @@ congestion and impairment engines — see ``docs/architecture.md`` §10):
   once per window with the other scan outputs.  :class:`MetricsResult`
   aggregates (totals, percentiles, per-edge rollups) and exports JSONL.
 * :mod:`repro.obs.profiler` — ``jax.profiler`` hooks.
-  :func:`profile_trace` captures a device profile for a whole run;
-  :func:`annotate` / :func:`step_annotation` mark dispatch groups and
-  scan windows inside it, and degrade to shared no-op context managers
-  when no profile is active.
+  :func:`profile_trace` captures a device profile for a whole run and
+  sets the flag that makes spans annotate it.
 """
 from .trace import (
     CAT_BUILD,
@@ -30,6 +30,7 @@ from .trace import (
     CAT_IO,
     CAT_METRICS,
     CAT_SCHED,
+    CAT_WAIT,
     Stopwatch,
     TraceRecorder,
     active_recorder,
@@ -47,7 +48,7 @@ from .metrics import (
     MetricsResult,
 )
 from .export import AsyncJsonlWriter
-from .profiler import annotate, profile_trace, profiling_active, step_annotation
+from .profiler import profile_trace, profiling_active
 
 __all__ = [
     "CAT_BUILD",
@@ -57,6 +58,7 @@ __all__ = [
     "CAT_IO",
     "CAT_METRICS",
     "CAT_SCHED",
+    "CAT_WAIT",
     "Stopwatch",
     "TraceRecorder",
     "active_recorder",
@@ -71,8 +73,6 @@ __all__ = [
     "MetricsFrame",
     "MetricsResult",
     "AsyncJsonlWriter",
-    "annotate",
     "profile_trace",
     "profiling_active",
-    "step_annotation",
 ]

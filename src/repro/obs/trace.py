@@ -1,15 +1,29 @@
 """Host span tracing — a thread-aware recorder emitting Chrome trace JSON.
 
-The recorder is process-wide and explicitly installed
-(:func:`start_trace` / :func:`recording`); until then every
-:class:`span` is inert: ``__enter__``/``__exit__`` cost two
-``time.perf_counter()`` calls and one ``None`` check, nothing is
-allocated, and no lock is taken (``benchmarks/telemetry_overhead.py``
-gates that cost at < 1% of the 64-replication fleet bench point).  The
-two timestamps are kept even when disabled because the simulators derive
-their timing fields (``FleetResult.gen_s`` / ``dispatch_s``,
-``SimResult.timings``) from the very same spans via :class:`Stopwatch`
-— one instrument, two consumers.
+:class:`span` is the program's one instrument.  One ``with`` block
+feeds three consumers from the same ``perf_counter`` pair:
+
+* a :class:`Stopwatch` (``acc=``), whose totals become the simulators'
+  timing fields (``FleetResult.gen_s`` / ``dispatch_s`` / ``timings``,
+  ``SimResult.timings``);
+* the process-wide Chrome recorder, once one is installed
+  (:func:`start_trace` / :func:`recording`);
+* the ``jax.profiler`` trace, while :mod:`repro.obs.profiler` has a
+  profile active (its module-level ``_ACTIVE`` flag): the span opens a
+  ``TraceAnnotation`` of its name (a ``StepTraceAnnotation`` when given
+  ``step=``), so program spans land on the device trace's clock.  Spans
+  of category :data:`CAT_WAIT` only wait for another thread and open
+  none: a trace names a device gap by the most recently started host
+  annotation, and a wait would take the idle of the work it waits for.
+
+With neither recorder nor profile, ``__enter__``/``__exit__`` cost two
+``time.perf_counter()`` calls, one ``None`` check and one flag check;
+nothing is allocated, and no lock is taken but a :class:`Stopwatch`'s
+(``benchmarks/telemetry_overhead.py`` gates that cost at < 1% of the
+64-replication fleet bench point).
+
+:meth:`Stopwatch.count` adds integer counters (bytes, rows) at the same
+boundaries; an active recorder gets a ``"C"`` event for each.
 
 Events carry the recording thread's id and name, so spans from
 ``simulate_fleet``'s producer thread ("fleet-window-producer") and the
@@ -34,7 +48,11 @@ import os
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Union
+
+import jax
+
+from . import profiler as _profiler
 
 __all__ = [
     "CAT_GEN",
@@ -44,6 +62,7 @@ __all__ = [
     "CAT_METRICS",
     "CAT_IO",
     "CAT_COMPILE",
+    "CAT_WAIT",
     "TraceRecorder",
     "span",
     "instant",
@@ -65,6 +84,7 @@ CAT_DISPATCH = "dispatch"  # jitted fleet-program dispatch + materialization
 CAT_METRICS = "metrics"    # window metrics drain / satisfaction reductions
 CAT_IO = "io"              # telemetry export (JSONL writer thread)
 CAT_COMPILE = "compile"    # compile-cache misses (runner/policy binding)
+CAT_WAIT = "wait"          # blocked on another thread (never a profiler annotation)
 
 
 class TraceRecorder:
@@ -123,6 +143,22 @@ class TraceRecorder:
         }
         if args:
             ev["args"] = args
+        with self._lock:
+            self._note_thread(tid)
+            self._events.append(ev)
+
+    def add_counter(self, name: str, value: int) -> None:
+        """One counter ("C") event at the current time."""
+        tid = threading.get_ident()
+        ev = {
+            "name": name,
+            "cat": "count",
+            "ph": "C",
+            "ts": (time.perf_counter() - self._t0) * 1e6,
+            "pid": os.getpid(),
+            "tid": tid,
+            "args": {name: value},
+        }
         with self._lock:
             self._note_thread(tid)
             self._events.append(ev)
@@ -212,27 +248,40 @@ class span:
 
     Always measures (``s.elapsed_s`` is valid after exit — the simulators'
     timing fields are built from it); records a trace event only when a
-    process-wide recorder is active at ``__enter__``.  An exception inside
-    the block still closes and records the span.
+    process-wide recorder is active at ``__enter__``, and opens a profiler
+    annotation only when a profile is active then and ``cat`` is not
+    :data:`CAT_WAIT` (``step=`` makes it a ``StepTraceAnnotation``).  An
+    exception inside the block still closes and records the span.
     """
 
-    __slots__ = ("name", "cat", "args", "acc", "_t0", "_rec", "elapsed_s")
+    __slots__ = ("name", "cat", "args", "acc", "step", "_t0", "_rec", "_ann", "elapsed_s")
 
     def __init__(
         self,
         name: str,
         cat: str = CAT_SCHED,
         acc: Optional["Stopwatch"] = None,
+        step: Optional[int] = None,
         **args: Any,
     ) -> None:
         self.name = name
         self.cat = cat
         self.args = args or None
         self.acc = acc
+        self.step = step
         self.elapsed_s = 0.0
 
     def __enter__(self) -> "span":
         self._rec = _RECORDER  # snapshot: recorder swaps mid-span stay sane
+        # read through the module: the flag is flipped at run time
+        if _profiler._ACTIVE and self.cat != CAT_WAIT:
+            self._ann = (
+                jax.profiler.TraceAnnotation(self.name) if self.step is None
+                else jax.profiler.StepTraceAnnotation(self.name, step_num=self.step)
+            )
+            self._ann.__enter__()
+        else:
+            self._ann = None
         self._t0 = time.perf_counter()
         return self
 
@@ -244,6 +293,8 @@ class span:
         rec = self._rec
         if rec is not None:
             rec.add_complete(self.name, self.cat, self._t0, t1, self.args)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
 
 
 def instant(name: str, cat: str = CAT_COMPILE, **args: Any) -> None:
@@ -254,30 +305,50 @@ def instant(name: str, cat: str = CAT_COMPILE, **args: Any) -> None:
 
 
 class Stopwatch:
-    """Per-run accumulator of span durations, keyed by span name.
+    """Per-run accumulator of span durations and counts, keyed by name.
 
     ``simulate`` / ``simulate_fleet`` each create one and wire their spans
     through it (``sw.span(...)``), then read totals to fill their timing
     fields — the trace recorder and the result fields see the *same*
     ``perf_counter`` pairs, so enabling tracing cannot skew the numbers.
+    :meth:`count` accumulates integers (bytes, rows) beside the totals.
+    The fleet's producer and worker threads share one stopwatch, so
+    updates take a lock.
     """
 
-    __slots__ = ("totals",)
+    __slots__ = ("totals", "counts", "_lock")
 
     def __init__(self) -> None:
         self.totals: Dict[str, float] = {}
+        self.counts: Dict[str, int] = {}
+        self._lock = threading.Lock()
 
     def _add(self, name: str, elapsed_s: float) -> None:
-        self.totals[name] = self.totals.get(name, 0.0) + elapsed_s
+        with self._lock:
+            self.totals[name] = self.totals.get(name, 0.0) + elapsed_s
 
-    def span(self, name: str, cat: str = CAT_SCHED, **args: Any) -> span:
-        return span(name, cat, acc=self, **args)
+    def span(self, name: str, cat: str = CAT_SCHED, step: Optional[int] = None,
+             **args: Any) -> span:
+        return span(name, cat, acc=self, step=step, **args)
+
+    def count(self, name: str, n: int) -> None:
+        """Add ``n`` to counter ``name`` (and emit a ``"C"`` event of ``n``
+        to an active recorder)."""
+        n = int(n)
+        with self._lock:
+            self.counts[name] = self.counts.get(name, 0) + n
+        rec = _RECORDER
+        if rec is not None:
+            rec.add_counter(name, n)
 
     def total(self, *names: str) -> float:
         return sum(self.totals.get(n, 0.0) for n in names)
 
-    def as_dict(self) -> Dict[str, float]:
-        return dict(self.totals)
+    def as_dict(self) -> Dict[str, Union[float, int]]:
+        """Span totals (float seconds) and counters (integer counts:
+        ``*_bytes``, ``*/rows``), in one dict."""
+        with self._lock:
+            return {**self.totals, **self.counts}
 
 
 def save_chrome_trace(recorder: TraceRecorder, path) -> None:

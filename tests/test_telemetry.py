@@ -373,3 +373,185 @@ def test_profile_trace_raises_when_trace_fails(tmp_path, monkeypatch, failing):
     assert not profiling_active()
     with profile_trace(None):  # no profile asked for: nothing starts
         assert not profiling_active()
+
+
+# ---------------------------------------------------------------------------
+# one instrument: spans on the profiler's clock, counters at the boundaries
+# ---------------------------------------------------------------------------
+
+#: the dense fleet's and the online decision's spans that own device gaps
+ANNOTATED = {"gus/pad", "gus/call", "fleet/generate_traces", "fleet/arrivals",
+             "fleet/grid_build", "fleet/put", "fleet/drain", "fleet/window_metrics"}
+
+
+def _profiled_host_names(tmp_path, monkeypatch, active: bool) -> set:
+    """Host-plane event names of a real CPU profiler trace, started as the
+    benchmark harness starts its own (Python tracer off, host tracer level
+    2) with the program's ``_ACTIVE`` flag set to ``active``, around a tiny
+    dense fleet and one padded ``gus_schedule`` call."""
+    from jax.profiler import ProfileData
+
+    from repro.core import gus_schedule
+    from repro.core.instance import generate_instance, pad_instance
+    from repro.obs import profiler
+
+    inst = generate_instance(0)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    monkeypatch.setattr(profiler, "_ACTIVE", active)
+    try:
+        simulate_fleet(SPEC, cfg(), n_rep=2, seed=0)
+        np.asarray(gus_schedule(pad_instance(inst, 128)).j)
+    finally:
+        monkeypatch.setattr(profiler, "_ACTIVE", False)
+        jax.profiler.stop_trace()
+    (pb,) = sorted(tmp_path.rglob("*.xplane.pb"))
+    return {ev.name for plane in ProfileData.from_file(str(pb)).planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for ev in line.events}
+
+
+@pytest.mark.parametrize("active", [True, False])
+def test_spans_are_profiler_annotations_while_active(tmp_path, monkeypatch, active):
+    """Under an active profile every work span lands on the host plane and
+    the consumer's wait does not; with the flag off no span does."""
+    names = _profiled_host_names(tmp_path, monkeypatch, active)
+    assert "fleet/window_wait" not in names
+    if active:
+        assert ANNOTATED <= names
+        assert "fleet/dispatch" in names
+    else:
+        assert not names & (ANNOTATED | {"fleet/dispatch"})
+
+
+def test_profile_trace_passes_profiler_options(tmp_path, monkeypatch):
+    """``profile_trace`` starts the profiler as the benchmark does: host
+    tracer level 2, the Python tracer off; spans see the flag while the
+    block runs."""
+    from repro.obs import profile_trace, profiler, profiling_active
+
+    seen = {}
+
+    def start(log_dir, **kw):
+        seen.update(kw, log_dir=log_dir, active=profiler._ACTIVE)
+
+    monkeypatch.setattr(jax.profiler, "start_trace", start)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda *a, **k: None)
+    with profile_trace(tmp_path / "prof"):
+        assert profiling_active() and profiler._ACTIVE is True
+    assert profiler._ACTIVE is False and not seen["active"]
+    opts = seen["profiler_options"]
+    assert opts.host_tracer_level == 2
+    assert opts.python_tracer_level == 0
+    assert seen["log_dir"] == str(tmp_path / "prof")
+
+
+def test_fleet_counters_match_shapes():
+    """``fleet/h2d_bytes`` is the bytes of every host array placed for the
+    scan, ``fleet/rows`` the padded rows scheduled and
+    ``fleet/accounting_h2d_bytes`` the accounting's one upload, each
+    computed here from shapes and dtypes (3 reps in groups of 2, so one
+    padded replication; 3 frames in windows of 2)."""
+    from repro.core import EngineOptions
+    from repro.core.simulator import _pad_bucket
+
+    c = cfg(horizon_ms=9000.0)
+    fr = simulate_fleet(SPEC, c, n_rep=3, seed=0,
+                        options=EngineOptions(metrics=True, rep_group=2, window=2))
+    T, n_rep, R_pad = fr.n_frames, 3, 4
+    n_pad = _pad_bucket(int(fr.metrics.data["n_arrivals"].max()))
+    M, L = SPEC.n_servers, SPEC.acc.shape[1]
+    f4, b1 = 4, 1  # float32/int32/uint32, bool
+    per_frame = (n_pad * 5 * f4                  # cover, A, C, w_a, w_c
+                 + n_pad * M * L * (4 * f4 + b1)  # acc, ctime, v, u, avail
+                 + M * 2 * f4 + 2 * f4            # gamma, eta, max_as, max_cs
+                 + 2 * f4                         # PRNG key
+                 + n_pad * f4 + 2 * M * f4        # queueing delay, links, server up
+                 + f4)                            # real-row count (metrics)
+    assert fr.timings["fleet/h2d_bytes"] == R_pad * T * per_frame
+    assert fr.timings["fleet/rows"] == n_rep * T * n_pad
+    acct = n_pad * (2 * M * L * f4 + 4 * f4 + 2 * f4) + 2 * f4  # + assignment j, l
+    assert fr.timings["fleet/accounting_h2d_bytes"] == n_rep * T * acct
+    assert {"fleet/put", "fleet/drain"} <= set(fr.timings)
+
+
+def test_accounting_reads_only_its_declared_leaves():
+    """The fleet uploads and counts only ``SATISFACTION_LEAVES`` (and
+    ``CONGESTED_CTIME_LEAVES`` under congestion) for its accounting: the
+    functions give the same answers on an instance holding nothing else,
+    so no other leaf is uploaded behind ``fleet/accounting_h2d_bytes``."""
+    import dataclasses
+
+    from repro.core import gus_schedule
+    from repro.core.instance import generate_instance
+    from repro.core.queueing import CONGESTED_CTIME_LEAVES, congested_ctime
+    from repro.core.satisfaction import SATISFACTION_LEAVES, mean_us, satisfied_mask
+
+    inst = generate_instance(0)
+    a = gus_schedule(inst)
+
+    def only(leaves):
+        return dataclasses.replace(inst, **{f.name: None for f in dataclasses.fields(inst)
+                                            if f.name not in leaves})
+
+    sat = only(SATISFACTION_LEAVES)
+    np.testing.assert_array_equal(satisfied_mask(sat, a.j, a.l), satisfied_mask(inst, a.j, a.l))
+    np.testing.assert_array_equal(mean_us(sat, a.j, a.l), mean_us(inst, a.j, a.l))
+    M = inst.n_servers
+    tq = np.full(inst.n_requests, 5.0, np.float32)
+    phi_c, phi_e = np.linspace(1.0, 2.0, M, dtype=np.float32), np.full(M, 1.5, np.float32)
+    np.testing.assert_array_equal(congested_ctime(only(CONGESTED_CTIME_LEAVES), tq, phi_c, phi_e),
+                                  congested_ctime(inst, tq, phi_c, phi_e))
+
+
+def test_hier_post_is_split_into_drain_and_deaggregate():
+    from repro.core import EngineOptions
+
+    fr = simulate_fleet(SPEC, cfg(), policy="gus", n_rep=2, seed=0,
+                        options=EngineOptions(scheduler="hierarchical"))
+    assert {"fleet/hier_drain", "fleet/hier_deaggregate"} <= set(fr.timings)
+    assert "fleet/hier_post" not in fr.timings
+
+
+def test_stopwatch_counts_and_counter_events():
+    sw = Stopwatch()
+    with recording() as rec:
+        sw.count("unit/bytes", 3)
+        sw.count("unit/bytes", 4)
+        with sw.span("unit/a"):
+            pass
+    assert sw.as_dict()["unit/bytes"] == 7 and sw.total("unit/a") > 0.0
+    counters = [e for e in rec.to_chrome_trace()["traceEvents"] if e["ph"] == "C"]
+    assert [e["args"]["unit/bytes"] for e in counters] == [3, 4]
+    assert validate_chrome_trace(rec.to_chrome_trace()) == []
+
+
+def test_stopwatch_shared_across_threads_loses_no_update():
+    """The fleet's producer and worker threads share one stopwatch: many
+    threads with a short switch interval lose neither a count nor a span."""
+    import sys
+    import threading
+
+    sw = Stopwatch()
+    n_threads, n_iter = 16, 500
+
+    def work():
+        for _ in range(n_iter):
+            sw.count("unit/rows", 1)
+            sw._add("unit/s", 1.0)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert sw.counts["unit/rows"] == n_threads * n_iter
+    assert sw.totals["unit/s"] == n_threads * n_iter
