@@ -6,12 +6,13 @@ the *flattened* per-request view: every (i, j, l) tensor below has already been
 gathered at k = k_i.  This loses no generality and keeps GUS/ILP tensors at
 (N, M, L) instead of (N, M, K, L).
 
-All arrays are plain numpy in the generator and converted to a jax pytree
+All arrays are plain numpy in the generator and held in a jax pytree
 (`FlatInstance`) so the GUS scheduler can jit/vmap over batches of instances.
 """
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Optional
 
 import jax
@@ -50,6 +51,10 @@ class FlatInstance:
       eta:    (M,)  f32     communication capacity per server
       max_as: ()    f32     normalizer: max accuracy in the system
       max_cs: ()    f32     normalizer: worst-case completion time in the system
+
+    Leaves may be host (NumPy) or device (``jax.Array``) arrays: the jitted
+    schedulers take either, and ``pad_instance`` pads host leaves on the
+    host and device leaves on the device.
     """
 
     cover: jnp.ndarray
@@ -264,6 +269,40 @@ def generate_instance(
     return FlatInstance(**{k: jnp.asarray(val) for k, val in arrays.items()})
 
 
+#: the padding contract's fill value per request-axis leaf (``pad_instance``)
+_PAD_FILL = dict(
+    cover=0,
+    A=1e9,        # unreachable accuracy floor
+    C=-1.0,       # already-expired deadline
+    w_a=0.0,      # padded rows contribute zero US
+    w_c=0.0,
+    acc=0.0,
+    ctime=1e9,
+    v=0.0,        # free: no capacity consumed
+    u=0.0,
+    avail=False,  # infeasible everywhere
+)
+
+
+def _append_fill_rows(xp, rows: dict, n_pad: int) -> dict:
+    """Append ``_PAD_FILL`` rows to each leaf, with ``xp`` NumPy or jax.numpy."""
+    return {
+        k: xp.concatenate(
+            [x, xp.full((n_pad - x.shape[0],) + x.shape[1:], _PAD_FILL[k], x.dtype)])
+        for k, x in rows.items()
+    }
+
+
+#: the device path: every leaf padded in one program
+_pad_rows_device = jax.jit(partial(_append_fill_rows, jnp), static_argnums=1)
+
+
+def _host_array(x) -> np.ndarray:
+    """``x`` as NumPy, in the dtype JAX would give it (int64 -> int32...)."""
+    x = np.asarray(x)
+    return x.astype(jax.dtypes.canonicalize_dtype(x.dtype), copy=False)
+
+
 def pad_instance(inst: FlatInstance, n_pad: int) -> FlatInstance:
     """Pad the request axis of an (unbatched) instance to ``n_pad`` rows.
 
@@ -275,6 +314,14 @@ def pad_instance(inst: FlatInstance, n_pad: int) -> FlatInstance:
     requests by ascending index and padded rows sit at the end, the first
     ``N`` assignments are identical to running on the unpadded instance.
 
+    Where the padding runs follows where the leaves live.  If no
+    request-axis leaf is a ``jax.Array``, the rows are appended with NumPy
+    on the host and the result's leaves stay host arrays (the scheduler's
+    jitted call moves them to the device with its arguments); otherwise
+    one jitted program pads every leaf on the device.  Both give the same
+    values and dtypes (``_PAD_FILL``); the ``gus/pad`` span's ``path`` arg
+    says which ran.
+
     Server-axis leaves (gamma, eta) and scalars (max_as, max_cs) pass
     through untouched.
     """
@@ -283,29 +330,15 @@ def pad_instance(inst: FlatInstance, n_pad: int) -> FlatInstance:
         return inst
     if n_pad < N:
         raise ValueError(f"cannot pad {N} requests down to {n_pad}")
-    p = n_pad - N
-
-    def _pad(x, fill):
-        x = jnp.asarray(x)
-        return jnp.concatenate([x, jnp.full((p,) + x.shape[1:], fill, x.dtype)])
-
-    with span("gus/pad", CAT_BUILD, n=N, n_pad=n_pad):
-        return FlatInstance(
-            cover=_pad(inst.cover, 0),
-            A=_pad(inst.A, 1e9),        # unreachable accuracy floor
-            C=_pad(inst.C, -1.0),       # already-expired deadline
-            w_a=_pad(inst.w_a, 0.0),    # padded rows contribute zero US
-            w_c=_pad(inst.w_c, 0.0),
-            acc=_pad(inst.acc, 0.0),
-            ctime=_pad(inst.ctime, 1e9),
-            v=_pad(inst.v, 0.0),
-            u=_pad(inst.u, 0.0),
-            avail=_pad(inst.avail, False),
-            gamma=inst.gamma,
-            eta=inst.eta,
-            max_as=inst.max_as,
-            max_cs=inst.max_cs,
-        )
+    rows = {k: getattr(inst, k) for k in _PAD_FILL}
+    on_device = any(isinstance(x, jax.Array) for x in rows.values())
+    with span("gus/pad", CAT_BUILD, n=N, n_pad=n_pad,
+              path="device" if on_device else "host"):
+        if on_device:
+            padded = _pad_rows_device(rows, n_pad)
+        else:
+            padded = _append_fill_rows(np, {k: _host_array(x) for k, x in rows.items()}, n_pad)
+        return dataclasses.replace(inst, **padded)
 
 
 def generate_batch(seed: int, n: int, cfg: Optional[GeneratorConfig] = None):

@@ -253,8 +253,12 @@ def test_stopwatch_accumulates_with_tracing_off():
 
 
 def test_recording_scopes_and_schema(tmp_path):
+    from repro.core.instance import generate_instance, pad_instance
+
     with recording() as rec:
         simulate_fleet(SPEC, cfg(), n_rep=2, seed=0, metrics=True)
+        pad_instance(generate_instance(0, as_numpy=True), 128)
+        pad_instance(generate_instance(0), 128)
     assert active_recorder() is None
     assert {"gen", "build", "dispatch", "metrics"} <= rec.categories()
     assert "fleet/dispatch" in rec.span_names()
@@ -263,6 +267,10 @@ def test_recording_scopes_and_schema(tmp_path):
     obj = json.loads(path.read_text())
     assert validate_chrome_trace(obj) == []
     assert any(e["ph"] == "M" for e in obj["traceEvents"])
+    # the padding span names its path: NumPy input on the host, jax.Array
+    # input on the device
+    pads = [e["args"]["path"] for e in obj["traceEvents"] if e.get("name") == "gus/pad"]
+    assert pads == ["host", "device"]
     # after the recorder is gone, new spans don't grow it
     n = len(rec)
     with span("unit/after"):
