@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import os
 from functools import partial
 
@@ -42,7 +43,7 @@ import numpy as np
 from repro.kernels.gus_pallas import gus_assign_pallas, pallas_interpret
 from repro.obs.trace import CAT_SCHED, span
 
-from .instance import FlatInstance
+from .instance import FlatInstance, _host_array
 from .satisfaction import hard_feasible, us_tensor
 
 __all__ = [
@@ -138,6 +139,72 @@ def gus_schedule_np(inst: FlatInstance) -> Assignment:
 
 
 # ---------------------------------------------------------------------------
+# One upload per call: host leaves packed into one buffer
+# ---------------------------------------------------------------------------
+
+#: ``FlatInstance``'s leaves, in field order
+_FIELDS = tuple(f.name for f in dataclasses.fields(FlatInstance))
+
+
+@functools.lru_cache(maxsize=None)
+def _upload_layout(specs: tuple) -> tuple:
+    """Where each packed leaf sits in the upload buffer.
+
+    ``specs`` is ``((name, shape, dtype), ...)``; the result is
+    ``((name, word_offset, n_words, shape, dtype), ...)``, every leaf
+    starting on a 32-bit word.  Static and hashable: one padding bucket
+    gives one layout, and so one compiled program."""
+    layout, off = [], 0
+    for name, shape, dt in specs:
+        n = -(-math.prod(shape) * dt.itemsize // 4)
+        layout.append((name, off, n, shape, dt))
+        off += n
+    return tuple(layout)
+
+
+def _host_leaves(inst: FlatInstance) -> dict:
+    """The leaves ``gus_schedule`` packs: NumPy arrays and scalars, in the
+    dtypes JAX would give them, unless a leaf is a tracer (called under a
+    transform, where nothing is uploaded)."""
+    leaves = {k: getattr(inst, k) for k in _FIELDS}
+    if any(isinstance(x, jax.core.Tracer) for x in leaves.values()):
+        return {}
+    return {k: _host_array(x) for k, x in leaves.items()
+            if isinstance(x, (np.ndarray, np.generic))}
+
+
+def _pack(host: dict, layout: tuple) -> np.ndarray:
+    """The host leaves' bytes, one ``uint32`` buffer laid out by ``layout``."""
+    words = np.empty(sum(n for _, _, n, _, _ in layout), np.uint32)
+    b = words.view(np.uint8)
+    for (_, off, _, _, _), x in zip(layout, host.values()):
+        b[4 * off:4 * off + x.nbytes] = np.ascontiguousarray(x).reshape(-1).view(np.uint8)
+    return words
+
+
+def _unpack(words: jnp.ndarray, layout: tuple) -> dict:
+    """Inverse of :func:`_pack`, inside the jitted program: each leaf's
+    words sliced out and reinterpreted bit for bit (``bool`` as ``!= 0``).
+    Leaves are at most 32 bits wide, as JAX canonicalises them."""
+    out = {}
+    for name, off, n, shape, dt in layout:
+        x = words[off:off + n]
+        if dt.itemsize < 4:  # several elements per word: split it
+            x = jax.lax.bitcast_convert_type(x, np.dtype(f"uint{8 * dt.itemsize}"))
+            x = x.reshape(-1)[:math.prod(shape)]
+        x = x.reshape(shape)
+        out[name] = x != 0 if dt == np.bool_ else jax.lax.bitcast_convert_type(x, dt)
+    return out
+
+
+def _with_unpacked(inst: FlatInstance, words, layout: tuple) -> FlatInstance:
+    """``inst`` with the leaves packed into ``words`` put back."""
+    if words is None:
+        return inst
+    return dataclasses.replace(inst, **_unpack(words, layout))
+
+
+# ---------------------------------------------------------------------------
 # Pure-JAX implementation
 # ---------------------------------------------------------------------------
 
@@ -171,14 +238,18 @@ def _gus_body(i, state, *, inst, us, feas):
     return gamma, eta, out_j, out_l
 
 
-@partial(jax.jit, static_argnames=("relax_compute", "relax_comm"))
+@partial(jax.jit, static_argnames=("layout", "relax_compute", "relax_comm"))
 def _gus_schedule_xla(
     inst: FlatInstance,
+    words=None,
     *,
+    layout: tuple = (),
     relax_compute: bool = False,
     relax_comm: bool = False,
 ) -> Assignment:
-    """The jitted XLA implementation (the default backend)."""
+    """The jitted XLA implementation (the default backend).  Leaves of
+    ``inst`` that are ``None`` arrive packed in ``words`` (``layout``)."""
+    inst = _with_unpacked(inst, words, layout)
     us = us_tensor(inst)
     feas = hard_feasible(inst)
     N = us.shape[0]
@@ -202,16 +273,20 @@ def _relaxed_budgets(inst: FlatInstance, relax_compute: bool, relax_comm: bool):
     return gamma0, eta0
 
 
-@partial(jax.jit, static_argnames=("relax_compute", "relax_comm", "interpret"))
+@partial(jax.jit, static_argnames=("layout", "relax_compute", "relax_comm", "interpret"))
 def _gus_schedule_pallas(
     inst: FlatInstance,
+    words=None,
     *,
+    layout: tuple = (),
     relax_compute: bool = False,
     relax_comm: bool = False,
     interpret: bool,
 ) -> Assignment:
     """Single-frame entry to the fused Pallas kernel (batch of one grid
-    program; ``vmap`` lifts it to one program per batched frame)."""
+    program; ``vmap`` lifts it to one program per batched frame).  Packed
+    leaves as in :func:`_gus_schedule_xla`."""
+    inst = _with_unpacked(inst, words, layout)
     gamma0, eta0 = _relaxed_budgets(inst, relax_compute, relax_comm)
     add = lambda x: jnp.asarray(x)[None]  # noqa: E731 — lift to batch of 1
     j, l = gus_assign_pallas(
@@ -255,17 +330,28 @@ def gus_schedule(
     dropped).  ``backend`` selects the implementation (``"xla"`` jitted
     loop, ``"pallas"`` fused kernel; ``None`` defers to the
     ``REPRO_GUS_BACKEND`` environment variable) — assignments are
-    bit-identical across backends."""
+    bit-identical across backends.
+
+    Leaves that are NumPy arrays are written into one buffer on the host,
+    uploaded with one ``jax.device_put`` and unpacked bit for bit inside
+    the jitted program, so a host-side frame costs one transfer and one
+    dispatch; ``jax.Array`` leaves are passed as they are, and under a
+    transform (tracer leaves) nothing is packed.  The ``gus/call`` span's
+    ``h2d_bytes`` / ``h2d_transfers`` args count the upload."""
     backend = resolve_gus_backend(backend)
-    with span("gus/call", CAT_SCHED, backend=backend):
+    host = _host_leaves(inst)
+    layout = _upload_layout(tuple((k, x.shape, x.dtype) for k, x in host.items()))
+    with span("gus/call", CAT_SCHED, backend=backend,
+              h2d_bytes=sum(4 * n for _, _, n, _, _ in layout),
+              h2d_transfers=int(bool(host))):
+        words = None
+        if host:
+            inst = dataclasses.replace(inst, **dict.fromkeys(host))
+            words = jax.device_put(_pack(host, layout))
+        kw = dict(layout=layout, relax_compute=relax_compute, relax_comm=relax_comm)
         if backend == "pallas":
-            return _gus_schedule_pallas(
-                inst, relax_compute=relax_compute, relax_comm=relax_comm,
-                interpret=pallas_interpret(),
-            )
-        return _gus_schedule_xla(
-            inst, relax_compute=relax_compute, relax_comm=relax_comm
-        )
+            return _gus_schedule_pallas(inst, words, interpret=pallas_interpret(), **kw)
+        return _gus_schedule_xla(inst, words, **kw)
 
 
 @partial(jax.jit, static_argnames=("relax_compute", "relax_comm"))

@@ -316,8 +316,8 @@ def pad_instance(inst: FlatInstance, n_pad: int) -> FlatInstance:
 
     Where the padding runs follows where the leaves live.  If no
     request-axis leaf is a ``jax.Array``, the rows are appended with NumPy
-    on the host and the result's leaves stay host arrays (the scheduler's
-    jitted call moves them to the device with its arguments); otherwise
+    on the host and the result's leaves stay host arrays (``gus_schedule``
+    moves them to the device as one packed buffer); otherwise
     one jitted program pads every leaf on the device.  Both give the same
     values and dtypes (``_PAD_FILL``); the ``gus/pad`` span's ``path`` arg
     says which ran.

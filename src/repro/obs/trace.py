@@ -11,10 +11,11 @@ feeds three consumers from the same ``perf_counter`` pair:
 * the ``jax.profiler`` trace, while :mod:`repro.obs.profiler` has a
   profile active (its module-level ``_ACTIVE`` flag): the span opens a
   ``TraceAnnotation`` of its name (a ``StepTraceAnnotation`` when given
-  ``step=``), so program spans land on the device trace's clock.  Spans
-  of category :data:`CAT_WAIT` only wait for another thread and open
-  none: a trace names a device gap by the most recently started host
-  annotation, and a wait would take the idle of the work it waits for.
+  ``step=``), with its args as the event's stats, so program spans land
+  on the device trace's clock.  Spans of category :data:`CAT_WAIT` only
+  wait for another thread and open none: a trace names a device gap by
+  the most recently started host annotation, and a wait would take the
+  idle of the work it waits for.
 
 With neither recorder nor profile, ``__enter__``/``__exit__`` cost two
 ``time.perf_counter()`` calls, one ``None`` check and one flag check;
@@ -275,9 +276,10 @@ class span:
         self._rec = _RECORDER  # snapshot: recorder swaps mid-span stay sane
         # read through the module: the flag is flipped at run time
         if _profiler._ACTIVE and self.cat != CAT_WAIT:
+            args = self.args or {}
             self._ann = (
-                jax.profiler.TraceAnnotation(self.name) if self.step is None
-                else jax.profiler.StepTraceAnnotation(self.name, step_num=self.step)
+                jax.profiler.TraceAnnotation(self.name, **args) if self.step is None
+                else jax.profiler.StepTraceAnnotation(self.name, step_num=self.step, **args)
             )
             self._ann.__enter__()
         else:

@@ -456,6 +456,46 @@ def test_profile_trace_passes_profiler_options(tmp_path, monkeypatch):
     assert seen["log_dir"] == str(tmp_path / "prof")
 
 
+def test_gus_call_span_counts_upload(tmp_path, monkeypatch):
+    """``gus/call`` carries ``h2d_bytes`` / ``h2d_transfers``: one packed
+    upload of every host leaf for a host-padded frame, none for a frame on
+    the device; the same args reach the profiler trace as the event's
+    stats."""
+    from jax.profiler import ProfileData
+
+    from repro.core import gus_schedule
+    from repro.core.instance import generate_instance, pad_instance
+    from repro.obs import profiler
+
+    host = pad_instance(generate_instance(0, as_numpy=True), 128)
+    dev = jax.tree.map(jax.device_put, host)
+    packed = sum(-(-np.asarray(x).nbytes // 4) * 4 for x in jax.tree.leaves(host))
+    want = [(packed, 1), (0, 0)]
+
+    with recording() as rec:
+        for inst in (host, dev):
+            np.asarray(gus_schedule(inst).j)
+    calls = [e["args"] for e in rec.events() if e["name"] == "gus/call"]
+    assert [(a["h2d_bytes"], a["h2d_transfers"]) for a in calls] == want
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    monkeypatch.setattr(profiler, "_ACTIVE", True)
+    try:
+        for inst in (host, dev):
+            np.asarray(gus_schedule(inst).j)
+    finally:
+        monkeypatch.setattr(profiler, "_ACTIVE", False)
+        jax.profiler.stop_trace()
+    (pb,) = sorted(tmp_path.rglob("*.xplane.pb"))
+    stats = [dict(ev.stats) for plane in ProfileData.from_file(str(pb)).planes
+             if plane.name.startswith("/host:") for line in plane.lines
+             for ev in line.events if ev.name == "gus/call"]
+    assert [(st["h2d_bytes"], st["h2d_transfers"]) for st in stats] == want
+
+
 def test_fleet_counters_match_shapes():
     """``fleet/h2d_bytes`` is the bytes of every host array placed for the
     scan, ``fleet/rows`` the padded rows scheduled and

@@ -111,3 +111,27 @@ def test_gus_xla_compiles_for_v5e(one_chip):
     leaves = dict(zip(names, _shapes(one_chip, *_gus_specs(64, 128, 10, 10))))
     text = _compiled_text(_gus_schedule_batch_xla, [FlatInstance(**leaves)])
     assert "tpu_custom_call" not in text
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_gus_packed_upload_compiles_for_v5e(one_chip, backend):
+    """The online decision's program: one packed ``uint32`` buffer of a
+    host-padded Sec. IV frame, unpacked inside the jitted call."""
+    import dataclasses
+
+    import numpy as np
+
+    from repro.core import gus
+    from repro.core.instance import generate_instance, pad_instance
+
+    host = gus._host_leaves(pad_instance(generate_instance(0, as_numpy=True), 128))
+    layout = gus._upload_layout(tuple((k, x.shape, x.dtype) for k, x in host.items()))
+    words = jax.ShapeDtypeStruct((sum(n for _, _, n, _, _ in layout),), np.uint32,
+                                 sharding=one_chip)
+    inst = gus.FlatInstance(**dict.fromkeys(f.name for f in dataclasses.fields(gus.FlatInstance)))
+    if backend == "pallas":
+        lowered = gus._gus_schedule_pallas.lower(inst, words, layout=layout, interpret=False)
+    else:
+        lowered = gus._gus_schedule_xla.lower(inst, words, layout=layout)
+    text = lowered.compile().as_text()
+    assert ("tpu_custom_call" in text) == (backend == "pallas")
