@@ -337,21 +337,39 @@ def gus_schedule(
     the jitted program, so a host-side frame costs one transfer and one
     dispatch; ``jax.Array`` leaves are passed as they are, and under a
     transform (tracer leaves) nothing is packed.  The ``gus/call`` span's
-    ``h2d_bytes`` / ``h2d_transfers`` args count the upload."""
+    ``h2d_bytes`` / ``h2d_transfers`` args count the upload.
+
+    A concrete call's answer is read on the host next by every caller in
+    the program (the online controller, ``simulate()``, the baselines), so
+    the copies of ``j`` and ``l`` to the host are started right after
+    dispatch: they run as soon as the program ends, and
+    ``np.asarray`` of the returned arrays costs no further round trip.
+    The result is still an ``Assignment`` of ``jax.Array``s, so a caller
+    may keep working on the device.  Under a transform (the outputs are
+    tracers) no copy is started.  The span's ``d2h_transfers`` /
+    ``d2h_bytes`` args count the answer started to the host (``0`` and
+    ``0`` otherwise)."""
     backend = resolve_gus_backend(backend)
     host = _host_leaves(inst)
     layout = _upload_layout(tuple((k, x.shape, x.dtype) for k, x in host.items()))
     with span("gus/call", CAT_SCHED, backend=backend,
               h2d_bytes=sum(4 * n for _, _, n, _, _ in layout),
-              h2d_transfers=int(bool(host))):
+              h2d_transfers=int(bool(host))) as s:
         words = None
         if host:
             inst = dataclasses.replace(inst, **dict.fromkeys(host))
             words = jax.device_put(_pack(host, layout))
         kw = dict(layout=layout, relax_compute=relax_compute, relax_comm=relax_comm)
         if backend == "pallas":
-            return _gus_schedule_pallas(inst, words, interpret=pallas_interpret(), **kw)
-        return _gus_schedule_xla(inst, words, **kw)
+            out = _gus_schedule_pallas(inst, words, interpret=pallas_interpret(), **kw)
+        else:
+            out = _gus_schedule_xla(inst, words, **kw)
+        fetch = not isinstance(out.j, jax.core.Tracer)
+        if fetch:
+            out.j.copy_to_host_async()
+            out.l.copy_to_host_async()
+        s.note(d2h_transfers=int(fetch), d2h_bytes=out.j.nbytes + out.l.nbytes if fetch else 0)
+        return out
 
 
 @partial(jax.jit, static_argnames=("relax_compute", "relax_comm"))

@@ -298,6 +298,17 @@ class span:
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
 
+    def note(self, **args: Any) -> None:
+        """Add args inside the block, for what is known only there; they
+        reach the recorder's event and the profiler annotation's stats
+        like those given at construction; with neither active it does
+        nothing."""
+        if self._rec is None and self._ann is None:
+            return
+        self.args = {**(self.args or {}), **args}
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
 
 def instant(name: str, cat: str = CAT_COMPILE, **args: Any) -> None:
     """Record an instant event (no-op when tracing is off)."""

@@ -459,8 +459,9 @@ def test_profile_trace_passes_profiler_options(tmp_path, monkeypatch):
 def test_gus_call_span_counts_upload(tmp_path, monkeypatch):
     """``gus/call`` carries ``h2d_bytes`` / ``h2d_transfers``: one packed
     upload of every host leaf for a host-padded frame, none for a frame on
-    the device; the same args reach the profiler trace as the event's
-    stats."""
+    the device; and ``d2h_transfers`` / ``d2h_bytes``: each frame's
+    answer (``j``, ``l``: 2 x 4 x N bytes) started back to the host.  The
+    same args reach the profiler trace as the event's stats."""
     from jax.profiler import ProfileData
 
     from repro.core import gus_schedule
@@ -470,13 +471,15 @@ def test_gus_call_span_counts_upload(tmp_path, monkeypatch):
     host = pad_instance(generate_instance(0, as_numpy=True), 128)
     dev = jax.tree.map(jax.device_put, host)
     packed = sum(-(-np.asarray(x).nbytes // 4) * 4 for x in jax.tree.leaves(host))
-    want = [(packed, 1), (0, 0)]
+    N = host.A.shape[0]
+    want = [(packed, 1, 1, 2 * 4 * N), (0, 0, 1, 2 * 4 * N)]
+    keys = ("h2d_bytes", "h2d_transfers", "d2h_transfers", "d2h_bytes")
 
     with recording() as rec:
         for inst in (host, dev):
             np.asarray(gus_schedule(inst).j)
     calls = [e["args"] for e in rec.events() if e["name"] == "gus/call"]
-    assert [(a["h2d_bytes"], a["h2d_transfers"]) for a in calls] == want
+    assert [tuple(a[k] for k in keys) for a in calls] == want
 
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -493,7 +496,7 @@ def test_gus_call_span_counts_upload(tmp_path, monkeypatch):
     stats = [dict(ev.stats) for plane in ProfileData.from_file(str(pb)).planes
              if plane.name.startswith("/host:") for line in plane.lines
              for ev in line.events if ev.name == "gus/call"]
-    assert [(st["h2d_bytes"], st["h2d_transfers"]) for st in stats] == want
+    assert [tuple(st[k] for k in keys) for st in stats] == want
 
 
 def test_fleet_counters_match_shapes():
