@@ -156,12 +156,7 @@ def run_users_sweep(*, tiny: bool, repeats: int) -> list:
     aggregates instead of 10^5 individual users.
 
     The sweep runs with **admission control and link impairments enabled**
-    (class-level shedding + per-member realized channels — the composition
-    the PR-9 host loop hard-raised on), and the largest point is re-timed
-    under ``REPRO_HIER_HOST_LOOP=1`` (the retained PR-9 per-window host
-    loop): the device pipeline must come in measurably faster in the full
-    sweep (the 10^5-users point); in ``--tiny`` the speedup is reported
-    but not asserted."""
+    (class-level shedding + per-member realized channels)."""
     n_edge = 20
     spec = demo_cluster_spec(n_edge=n_edge, n_cloud=1, n_services=5, n_variants=10)
     cfg = SimConfig(
@@ -179,7 +174,7 @@ def run_users_sweep(*, tiny: bool, repeats: int) -> list:
     # materialized columnar traces (streaming=False keeps arrivals as array
     # slices, never per-request objects) + per-frame windows with prefetch:
     # the producer thread builds frame k+1's class grid while the device
-    # crunches frame k — the overlap the per-window host loop cannot have
+    # crunches frame k
     opts = EngineOptions(
         scheduler="hierarchical", window=1, prefetch=2, streaming=False
     )
@@ -209,36 +204,6 @@ def run_users_sweep(*, tiny: bool, repeats: int) -> list:
         print(f"users_sweep,users={users},n_requests={fr.n_requests},"
               f"{row['wall_s']}s,{row['reqs_per_s']} req/s", flush=True)
 
-    # PR-9 host-loop baseline at the largest point (same trace; the host
-    # loop ignores admission — it predates it — so it does strictly *less*
-    # work and still has to lose on wall time)
-    top = rows[-1]
-    scn = dataclasses.replace(
-        base, rate_per_edge_per_s=top["users_per_frame"] / (n_edge * frame_s)
-    )
-    host_wall = float("inf")
-    os.environ["REPRO_HIER_HOST_LOOP"] = "1"
-    try:
-        for _ in range(max(1, repeats)):
-            t0 = time.perf_counter()
-            simulate_fleet(
-                spec, cfg, policy="gus", scenario=scn, n_rep=1, seed=0,
-                options=opts,
-            )
-            host_wall = min(host_wall, time.perf_counter() - t0)
-    finally:
-        del os.environ["REPRO_HIER_HOST_LOOP"]
-    top["host_loop_wall_s"] = round(host_wall, 4)
-    top["device_speedup_vs_host"] = round(host_wall / max(top["wall_s"], 1e-9), 2)
-    print(f"users_sweep,host-loop baseline at {top['users_per_frame']} "
-          f"users/frame: {top['host_loop_wall_s']}s vs device "
-          f"{top['wall_s']}s ({top['device_speedup_vs_host']}x)", flush=True)
-    if not tiny and top["device_speedup_vs_host"] <= 1.0:
-        raise SystemExit(
-            f"users_sweep gate: device hier pipeline ({top['wall_s']}s) is "
-            f"not faster than the PR-9 host loop ({top['host_loop_wall_s']}s) "
-            f"at the {top['users_per_frame']}-users point"
-        )
     import math as _math
 
     for lo, hi in zip(rows, rows[1:]):

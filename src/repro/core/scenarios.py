@@ -117,7 +117,7 @@ class RequestColumns:
     """Columnar arrival trace — the struct-of-arrays twin of ``List[Request]``.
 
     The vectorized generator emits this so the fleet's grid builder
-    (``repro.core.simulator._build_frame_batch``) can fill whole frames with
+    (``repro.core.frames._build_frame_batch``) can fill whole frames with
     array slices instead of per-request Python attribute reads.  Arrays are
     parallel, sorted by ``arrival_ms``; float columns stay float64 (the RNG's
     native width) and are narrowed to float32 exactly where the per-request

@@ -48,6 +48,10 @@ backlogs, EMA load estimates, bandwidth-estimator state) threaded through
 1-D ``("rep",)`` device mesh (``devices=``) and can run its frame scan in
 bounded-memory windows (``window=``) — both bit-identical to the
 single-device, fully-materialized program.  See the function docstring.
+
+The frame layer both entry points stand on (arrival sources, padding
+buckets, per-frame tensors, the fleet's grid builder, per-frame budgets)
+is :mod:`repro.core.frames`.
 """
 from __future__ import annotations
 
@@ -59,7 +63,7 @@ import threading
 import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
-from typing import Callable, Dict, List, Optional, Sequence, Union
+from typing import Callable, Dict, List, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -76,9 +80,19 @@ from repro.obs.trace import (
     CAT_WAIT,
     Stopwatch,
     instant,
-    span,
 )
 
+from .frames import (
+    _apply_mobility_inplace,
+    _ArrivalSource,
+    _build_frame_batch,
+    _build_frame_instance,
+    _frame_budgets,
+    _frame_budgets_batch,
+    _pad_bucket,
+    _pad_bucket_fine,
+    _RepFrameSource,
+)
 from .gus import Assignment, gus_backend_fn, gus_schedule
 from .impairments import (
     AdmissionConfig,
@@ -117,21 +131,8 @@ from .satisfaction import (
     satisfied_mask,
     us_tensor,
 )
-from .scenarios import (
-    Request,
-    RequestColumns,
-    Scenario,
-    _resolve_rng_mode,
-    bucket_arrivals,
-    bucket_columns,
-    get_scenario,
-)
-from .streaming import (
-    ArrivalStream,
-    max_frame_arrivals,
-    stream_trace,
-    stream_trace_columns,
-)
+from .scenarios import Request, RequestColumns, Scenario, get_scenario
+from .streaming import ArrivalStream, max_frame_arrivals
 
 __all__ = [
     "ClusterSpec",
@@ -267,374 +268,12 @@ class SimResult:
         return d
 
 
-def _pad_bucket(n: int) -> int:
-    """Round the frame's queue length up to a power-of-two bucket (min 4) so
-    the jitted scheduler compiles once per bucket, not once per queue length."""
-    return max(4, 1 << max(n - 1, 0).bit_length())
-
-
-def _pad_bucket_fine(n: int) -> int:
-    """Bucket schedule for the hierarchical class axis: powers of two up to
-    4096, multiples of 1024 above.
-
-    Class counts sit wherever quantization puts them — at 10^5 users/frame
-    the mega-city lands near 19k classes, which the power-of-two schedule
-    pads to 32768 (≈70% dead rows in every (C, M, L) tensor *and* ≈70%
-    dead steps in the allocator's scan over classes).  Above 4096 the waste
-    is capped at ~5% instead; the compile-cache cost stays bounded because
-    a window's bucket moves only when its class count crosses a 1024
-    boundary, and city-scale frames drawn from one arrival process cluster
-    tightly."""
-    if n <= 4096:
-        return max(4, 1 << max(n - 1, 0).bit_length())
-    return ((n + 1023) // 1024) * 1024
-
-
 #: default width of a fleet replication group — the unit of device dispatch
 #: in :func:`simulate_fleet`.  One program is compiled per group shape and
 #: reused for every group on every device, which is what keeps multi-device
 #: results bit-identical to the single-device run.  Fleets with
 #: ``n_rep <= FLEET_REP_GROUP`` run as a single group (the legacy layout).
 FLEET_REP_GROUP = 8
-
-
-def _frame_arrays(
-    reqs: Sequence[Request], spec: ClusterSpec, cfg: SimConfig, now_ms: float, bw_est: float,
-    link=None, lean: bool = False,
-) -> Dict[str, np.ndarray]:
-    """Numpy request-row tensors for one frame, using the scheduler's
-    *estimated* bandwidth for comm delays — shared by
-    :func:`_build_frame_instance` and the fleet's batched grid builder.
-
-    ``reqs`` is either a list of :class:`Request` objects or a
-    :class:`~repro.core.scenarios.RequestColumns` view (the vectorized
-    trace); the columnar branch narrows the same float64 values to float32,
-    so the two layouts produce bit-identical tensors from identical draws.
-
-    ``link`` is an optional pair of per-request ``(bandwidth_scale,
-    extra_latency_ms)`` arrays from the resilience engine, gathered by each
-    request's covering edge: transfer time becomes ``size / (bw * scale) +
-    lat``.  ``None`` (impairments off) leaves the formula untouched; at
-    amplitude 0 the scale is exactly 1.0 and the latency exactly 0.0, so
-    the result is bitwise identical either way.
-    """
-    M = spec.n_servers
-    L = spec.acc.shape[1]
-    N = len(reqs)
-    is_cloud = spec.is_cloud()
-
-    if isinstance(reqs, RequestColumns):
-        cover = reqs.cover.astype(np.int32)
-        A = reqs.A.astype(np.float32)
-        C = reqs.C.astype(np.float32)
-        Tq = (now_ms - reqs.arrival_ms).astype(np.float32)
-        size = reqs.size_bytes.astype(np.float32)
-        svc = reqs.service.astype(np.int32)
-    else:
-        cover = np.array([r.cover for r in reqs], np.int32)
-        A = np.array([r.A for r in reqs], np.float32)
-        C = np.array([r.C for r in reqs], np.float32)
-        Tq = np.array([now_ms - r.arrival_ms for r in reqs], np.float32)
-        size = np.array([r.size_bytes for r in reqs], np.float32)
-        svc = np.array([r.service for r in reqs], np.int32)
-
-    local = cover[:, None] == np.arange(M)[None, :]
-    transfer = size[:, None] / bw_est
-    if link is not None:
-        # bandwidth scales divide the transfer time, extra latency adds;
-        # at identity (scale 1.0, lat 0.0) both ops are bitwise no-ops
-        bw_scale, extra_lat = link
-        transfer = transfer / np.asarray(bw_scale, np.float64)[:, None] \
-            + np.asarray(extra_lat, np.float64)[:, None]
-    comm = transfer + np.where(is_cloud[None, :], spec.cloud_extra_delay, 0.0)
-    comm = np.where(local, 0.0, comm)
-
-    proc = spec.proc_ms[:, svc, :].transpose(1, 0, 2)       # (N, M, L)
-    ctime = Tq[:, None, None] + proc + comm[:, :, None]
-    if lean:
-        # class-grid builder fast path: the candidate gathers/broadcasts
-        # (acc, avail, v, u) are pure float32 lookups of spec tensors and
-        # are rebuilt on device from these per-row vectors — only ctime's
-        # float64 link math must stay host-side to agree bitwise with the
-        # request-level paths
-        return dict(cover=cover, A=A, C=C, ctime=ctime, svc=svc, size=size)
-    avail = spec.placed[:, svc, :].transpose(1, 0, 2)
-    # broadcast view, not a copy: every consumer only reads (scatter/slice
-    # assignment or jnp.asarray), and skipping the 16MB materialization
-    # keeps the producer thread off the critical path
-    acc = np.broadcast_to(spec.acc[svc][:, None, :], (N, M, L))
-    u = np.where(local[:, :, None], 0.0, (size / 1024.0)[:, None, None])
-    return dict(
-        cover=cover, A=A, C=C, acc=acc, ctime=ctime, v=proc,
-        u=np.broadcast_to(u, (N, M, L)), avail=avail,
-    )
-
-
-def _build_frame_instance(
-    reqs: Sequence[Request],
-    spec: ClusterSpec,
-    cfg: SimConfig,
-    now_ms: float,
-    bw_est: float,
-    max_cs: float,
-    gamma=None,
-    eta=None,
-    link=None,
-) -> FlatInstance:
-    """FlatInstance for the requests pending in this frame."""
-    N = len(reqs)
-    arr = _frame_arrays(reqs, spec, cfg, now_ms, bw_est, link=link)
-    return FlatInstance(
-        cover=jnp.asarray(arr["cover"]),
-        A=jnp.asarray(arr["A"]),
-        C=jnp.asarray(arr["C"]),
-        w_a=jnp.full((N,), cfg.w_a, jnp.float32),
-        w_c=jnp.full((N,), cfg.w_c, jnp.float32),
-        acc=jnp.asarray(arr["acc"], jnp.float32),
-        ctime=jnp.asarray(arr["ctime"], jnp.float32),
-        v=jnp.asarray(arr["v"], jnp.float32),
-        u=jnp.asarray(arr["u"], jnp.float32),
-        avail=jnp.asarray(arr["avail"]),
-        gamma=jnp.asarray(spec.gamma_frame if gamma is None else gamma, jnp.float32),
-        eta=jnp.asarray(spec.eta_frame if eta is None else eta, jnp.float32),
-        max_as=jnp.float32(cfg.max_as),
-        max_cs=jnp.float32(max_cs),
-    )
-
-
-def _build_frame_batch(
-    frames: List[List[Request]],
-    spec: ClusterSpec,
-    cfg: SimConfig,
-    frame_starts: Sequence[float],
-    budgets,
-    n_pad: int,
-    links=None,
-    lean: bool = False,
-) -> FlatInstance:
-    """Stacked, padded ``FlatInstance`` for a whole grid of frames at once.
-
-    ``links`` (optional, aligned with ``frames`` like ``budgets``) carries
-    each frame's per-*server* ``(bandwidth_scale, extra_latency_ms)`` pair
-    from the resilience engine; the builder gathers them per request by
-    covering edge and hands them to :func:`_frame_arrays`.
-
-    Fills preallocated numpy tensors frame by frame and converts each leaf
-    to a device array *once* — the fleet's hot-path grid builder.  With the
-    per-frame ``jnp`` round-trips gone, building a 10^3-frame window costs
-    milliseconds instead of seconds.  The pad-row fill constants mirror
-    :func:`repro.core.instance.pad_instance`, and values are bit-identical
-    to stacking ``pad_instance(_build_frame_instance(...), n_pad)`` per
-    frame (pinned by the sharded-fleet parity tests through the unchanged
-    sequential path).
-
-    A fully columnar grid (every frame a :class:`RequestColumns` — the
-    vectorized rng mode) skips the per-frame Python loop: the grid's
-    requests are concatenated, :func:`_frame_arrays` runs *once* over all
-    of them (its formulas are elementwise given each request's ``now_ms``),
-    and one fancy-indexed scatter per leaf writes the real rows — the same
-    values the per-frame fill writes, computed by the same elementwise ops.
-    """
-    F = len(frames)
-    M = spec.n_servers
-    L = spec.acc.shape[1]
-    cover = np.zeros((F, n_pad), np.int32)
-    A = np.full((F, n_pad), 1e9, np.float32)     # unreachable accuracy floor
-    C = np.full((F, n_pad), -1.0, np.float32)    # already-expired deadline
-    w_a = np.zeros((F, n_pad), np.float32)       # padded rows contribute zero US
-    w_c = np.zeros((F, n_pad), np.float32)
-    # ``lean`` (hierarchical fast path): the four candidate tensors that are
-    # pure spec gathers are never materialized on host — (F, 1, 1, 1)
-    # dummies hold their slots and the caller rebuilds them on device from
-    # the per-row ``svc``/``size`` vectors returned alongside the instance
-    big = (F, 1, 1, 1) if lean else (F, n_pad, M, L)
-    acc = np.zeros(big, np.float32)
-    ctime = np.full((F, n_pad, M, L), 1e9, np.float32)
-    v = np.zeros(big, np.float32)
-    u = np.zeros(big, np.float32)
-    avail = np.zeros(big, bool)
-    svc_p = np.zeros((F, n_pad), np.int32) if lean else None
-    size_p = np.zeros((F, n_pad), np.float32) if lean else None
-    gamma = np.zeros((F, M), np.float32)
-    eta = np.zeros((F, M), np.float32)
-    for i in range(F):
-        g, e = budgets[i]
-        gamma[i] = g
-        eta[i] = e
-    columnar = F > 0 and all(isinstance(b, RequestColumns) for b in frames)
-    if columnar:
-        lengths = np.fromiter((len(b) for b in frames), np.int64, F)
-        nn = int(lengths.sum())
-        if nn:
-            cat = RequestColumns.concatenate(frames)
-            row = np.repeat(np.arange(F), lengths)
-            now = np.repeat(
-                np.asarray(frame_starts, np.float64) + cfg.frame_ms, lengths
-            )
-            link = None
-            if links is not None:
-                cov = cat.cover.astype(np.intp)
-                sc = np.stack([l[0] for l in links])  # (F, M)
-                la = np.stack([l[1] for l in links])
-                link = (sc[row, cov], la[row, cov])
-            arr = _frame_arrays(
-                cat, spec, cfg, now, spec.bandwidth_true, link=link, lean=lean
-            )
-            # rows land at columns 0..n_i-1 of their frame by construction
-            # (``col`` above is a within-frame arange), so the scatter is
-            # really F contiguous slice writes — orders of magnitude fewer
-            # index computations than one 12M-element fancy-indexed store
-            # when frames hold 10^4+ classes
-            starts = np.cumsum(lengths) - lengths
-            for i in range(F):
-                n_i = int(lengths[i])
-                if n_i == 0:
-                    continue
-                sl = slice(int(starts[i]), int(starts[i]) + n_i)
-                cover[i, :n_i] = arr["cover"][sl]
-                A[i, :n_i] = arr["A"][sl]
-                C[i, :n_i] = arr["C"][sl]
-                w_a[i, :n_i] = cfg.w_a
-                w_c[i, :n_i] = cfg.w_c
-                ctime[i, :n_i] = arr["ctime"][sl]
-                if lean:
-                    svc_p[i, :n_i] = arr["svc"][sl]
-                    size_p[i, :n_i] = arr["size"][sl]
-                else:
-                    acc[i, :n_i] = arr["acc"][sl]
-                    v[i, :n_i] = arr["v"][sl]
-                    u[i, :n_i] = arr["u"][sl]
-                    avail[i, :n_i] = arr["avail"][sl]
-    else:
-        for i, (reqs, t0) in enumerate(zip(frames, frame_starts)):
-            n = len(reqs)
-            if n == 0:
-                continue
-            link = None
-            if links is not None:
-                cov = (
-                    reqs.cover.astype(np.intp)
-                    if isinstance(reqs, RequestColumns)
-                    else np.array([r.cover for r in reqs], np.intp)
-                )
-                sc, la = links[i]
-                link = (sc[cov], la[cov])
-            arr = _frame_arrays(
-                reqs, spec, cfg, t0 + cfg.frame_ms, spec.bandwidth_true,
-                link=link, lean=lean,
-            )
-            cover[i, :n] = arr["cover"]
-            A[i, :n] = arr["A"]
-            C[i, :n] = arr["C"]
-            w_a[i, :n] = cfg.w_a
-            w_c[i, :n] = cfg.w_c
-            ctime[i, :n] = arr["ctime"]
-            if lean:
-                svc_p[i, :n] = arr["svc"]
-                size_p[i, :n] = arr["size"]
-            else:
-                acc[i, :n] = arr["acc"]
-                v[i, :n] = arr["v"]
-                u[i, :n] = arr["u"]
-                avail[i, :n] = arr["avail"]
-    # numpy leaves on purpose: the fleet slices replication groups on host
-    # and device_puts each slice straight onto its target device (jnp ops
-    # consume numpy leaves transparently on the metrics path)
-    inst = FlatInstance(
-        cover=cover,
-        A=A,
-        C=C,
-        w_a=w_a,
-        w_c=w_c,
-        acc=acc,
-        ctime=ctime,
-        v=v,
-        u=u,
-        avail=avail,
-        gamma=gamma,
-        eta=eta,
-        max_as=np.full((F,), cfg.max_as, np.float32),
-        max_cs=np.full((F,), cfg.max_cs, np.float32),
-    )
-    if lean:
-        return inst, svc_p, size_p
-    return inst
-
-
-def _apply_mobility_inplace(
-    reqs: Sequence[Request], n_edge: int, move_prob: float, rng: np.random.Generator
-) -> None:
-    """Re-attach each pending request's covering edge with prob ``move_prob``.
-
-    Accepts a Request list or a :class:`RequestColumns` view — the RNG draw
-    count (two batches of ``len(reqs)``, nothing when the frame is empty) is
-    identical either way, so both trace layouts stay on one draw sequence.
-    """
-    if move_prob <= 0 or not reqs:
-        return
-    from .extensions import apply_mobility
-
-    if isinstance(reqs, RequestColumns):
-        reqs.cover = apply_mobility(
-            reqs.cover.astype(np.int32), n_edge, move_prob, rng
-        ).astype(np.int64)
-        return
-    cov = np.array([r.cover for r in reqs], np.int32)
-    cov = apply_mobility(cov, n_edge, move_prob, rng)
-    for r, c in zip(reqs, cov):
-        r.cover = int(c)
-
-
-def _frame_budgets(
-    spec: ClusterSpec, cfg: SimConfig, scn: Scenario, frame_start_ms: float,
-    engine: Optional[ResilienceEngine] = None,
-):
-    """Fresh per-frame (gamma, eta) budgets, masked by the scenario's
-    capacity stream (outages etc.) and — when a resilience engine is active —
-    by its stochastic MTBF/MTTR outage stream."""
-    g = spec.gamma_frame.astype(np.float64)
-    e = spec.eta_frame.astype(np.float64)
-    scale = scn.capacity_scale(frame_start_ms, cfg, spec.n_edge, spec.n_servers)
-    if scale is not None:
-        g = g * scale
-        e = e * scale
-    if engine is not None:
-        up = engine.capacity_scale(int(round(frame_start_ms / cfg.frame_ms)))
-        if up is not None:
-            g = g * up
-            e = e * up
-    return g.copy(), e.copy()
-
-
-def _frame_budgets_batch(
-    spec: ClusterSpec, cfg: SimConfig, scn: Scenario,
-    frame_starts_ms: np.ndarray,
-    engine: Optional[ResilienceEngine] = None,
-):
-    """Vectorized :func:`_frame_budgets` over a window of frame starts.
-
-    One ``capacity_scale_batch`` call replaces F scalar hook calls — the
-    host cost that dominated ``gen_s`` at mega-city frame counts.  Returns
-    ``(F, M)`` gamma and eta arrays, bit-identical to per-frame
-    :func:`_frame_budgets` calls: the batch hook fills unscaled frames with
-    exact ``1.0`` (the f64 multiplicative identity) and the same f64
-    multiply order is used either way.
-    """
-    t = np.asarray(frame_starts_ms, np.float64)
-    F = t.size
-    g = np.repeat(spec.gamma_frame.astype(np.float64)[None, :], F, axis=0)
-    e = np.repeat(spec.eta_frame.astype(np.float64)[None, :], F, axis=0)
-    scale = scn.capacity_scale_batch(t, cfg, spec.n_edge, spec.n_servers)
-    if scale is not None:
-        g = g * scale
-        e = e * scale
-    if engine is not None:
-        for i in range(F):
-            up = engine.capacity_scale(int(round(t[i] / cfg.frame_ms)))
-            if up is not None:
-                g[i] = g[i] * up
-                e[i] = e[i] * up
-    return g, e
 
 
 def _resolve_policy(
@@ -707,54 +346,6 @@ def _fold_hier_scheduler(pol, scheduler, opts, allow_backend=False):
             f"policy; it does not compose with policy {pol.name!r}"
         )
     return get_policy("gus-hier"), None
-
-
-class _ArrivalSource:
-    """Uniform pull interface over the two arrival engines.
-
-    *Materialized* (the default) keeps the legacy semantics and RNG
-    consumption bit-for-bit: the full trace is drawn up front from the
-    simulator's own generator.  *Streaming* wraps an
-    :class:`~repro.core.streaming.ArrivalStream` — memory stays bounded and
-    ``n_total`` counts submissions as they are emitted.
-    """
-
-    def __init__(self, reqs=None, stream: Optional[ArrivalStream] = None,
-                 limit: Optional[int] = None):
-        self._reqs = reqs
-        self._idx = 0
-        self._stream = stream
-        self._limit = limit
-        self._emitted = 0
-
-    def pull(self, t_ms: float) -> List[Request]:
-        """All not-yet-pulled arrivals with ``arrival_ms < t_ms``."""
-        if self._stream is None:
-            out = []
-            while self._idx < len(self._reqs) and self._reqs[self._idx].arrival_ms < t_ms:
-                out.append(self._reqs[self._idx])
-                self._idx += 1
-            return out
-        if self._limit is not None and self._emitted >= self._limit:
-            return []
-        out = self._stream.take_until(t_ms)
-        if self._limit is not None and self._emitted + len(out) > self._limit:
-            out = out[: self._limit - self._emitted]
-        self._emitted += len(out)
-        return out
-
-    @property
-    def exhausted(self) -> bool:
-        if self._stream is None:
-            return self._idx >= len(self._reqs)
-        return self._stream.exhausted or (
-            self._limit is not None and self._emitted >= self._limit
-        )
-
-    @property
-    def n_total(self) -> int:
-        """Total submissions (call after the run for the streaming source)."""
-        return len(self._reqs) if self._stream is None else self._emitted
 
 
 def simulate(
@@ -1367,74 +958,6 @@ def _resolve_fleet_devices(devices: Optional[int], n_rep: int) -> int:
     return devices
 
 
-class _RepFrameSource:
-    """One replication's per-frame request buckets, materialized or lazy.
-
-    *Materialized* reproduces the legacy fleet generation bit-for-bit: one
-    ``default_rng(seed + rep)`` drives ``generate_arrivals`` (or the trace
-    comes from :func:`stream_trace`) and then the per-frame mobility draws.
-    *Lazy* holds an :class:`ArrivalStream` and draws each frame's bucket on
-    demand, so a windowed fleet never materializes more than one window of
-    requests — the stream's chunking invariance makes the buckets (and the
-    mobility draw order) identical either way.
-
-    In ``rng_mode="vectorized"`` the materialized trace stays columnar
-    (:class:`RequestColumns` buckets) end to end — the grid builder fills
-    frames from array slices and per-request Python objects never exist;
-    the lazy stream uses the chunk-buffered vectorized engine.  Columnar
-    and lazy buckets carry the same values for the same seed (one chunk
-    code path underneath), so windowed==materialized holds in both modes.
-    """
-
-    def __init__(
-        self, scn, rep_seed, n_edge, n_services, cfg, T, use_stream, lazy,
-        rng_mode="paper-default",
-    ):
-        self.cfg = cfg
-        self.n_edge = n_edge
-        self.move_prob = cfg.move_prob if scn.move_prob is None else scn.move_prob
-        self.rng = np.random.default_rng(rep_seed)
-        self.stream: Optional[ArrivalStream] = None
-        self.buckets = None  # List[List[Request]] | List[RequestColumns]
-        vectorized = rng_mode == "vectorized"
-        if lazy:
-            self.stream = ArrivalStream(
-                scn, rep_seed, n_edge, n_services, cfg, rng_mode=rng_mode
-            )
-        elif vectorized:
-            if use_stream:
-                cols = stream_trace_columns(scn, rep_seed, n_edge, n_services, cfg)
-            else:
-                cols = scn.generate_arrivals_columns(self.rng, n_edge, n_services, cfg)
-            self.buckets = bucket_columns(cols, cfg.frame_ms, T)
-        else:
-            if use_stream:
-                reqs = stream_trace(scn, rep_seed, n_edge, n_services, cfg)
-            else:
-                reqs = scn.generate_arrivals(self.rng, n_edge, n_services, cfg)
-            self.buckets = bucket_arrivals(reqs, cfg.frame_ms, T)
-        self._next = 0
-
-    @property
-    def max_bucket(self) -> int:
-        """Largest per-frame bucket (materialized sources only)."""
-        return max((len(b) for b in self.buckets), default=0)
-
-    def take(self, upto_frame: int) -> List[List[Request]]:
-        """Buckets for frames ``[next, upto_frame)``, mobility applied in
-        frame order (the rep's single rng keeps the legacy draw sequence)."""
-        out = []
-        for tf in range(self._next, upto_frame):
-            if self.buckets is not None:
-                b = self.buckets[tf]
-            else:
-                b = self.stream.take_until((tf + 1) * self.cfg.frame_ms)
-            _apply_mobility_inplace(b, self.n_edge, self.move_prob, self.rng)
-            out.append(b)
-        self._next = upto_frame
-        return out
-
-
 @functools.lru_cache(maxsize=None)
 def _bound_policy_impl(pol: Policy, n_edge: int, n_servers: int):
     return pol.bind(n_edge, n_servers)
@@ -1587,6 +1110,135 @@ def _pad_reps(tree, pad_r: int):
     return jax.tree.map(pad, tree)
 
 
+class _WindowPipeline:
+    """The fleet's window pipeline: ``build_window(t0)`` for each start in
+    ``window_starts``, pulled in order by the consumer.
+
+    With ``prefetch > 0`` one producer thread ("fleet-window-producer")
+    builds windows ahead of the consumer, at most ``prefetch`` in flight on
+    a bounded queue; ``prefetch=0`` builds inline and starts no thread.
+    Entering returns ``(next_window, threaded)``: the consumer's pull, and
+    whether a producer runs.  A builder exception is handed to the
+    consumer's ``get`` and raised there.  ``__exit__`` stops the producer
+    (its puts poll a stop event), drains what it queued and joins it, on
+    success, early exit and error alike.
+    """
+
+    def __init__(self, build_window, window_starts, prefetch: int):
+        self._build = build_window
+        self._thread = None
+        if prefetch > 0 and len(window_starts) > 0:
+            self._queue: queue_mod.Queue = queue_mod.Queue(maxsize=prefetch)
+            self._stop = threading.Event()
+            self._thread = threading.Thread(
+                target=self._produce, args=(list(window_starts),),
+                name="fleet-window-producer", daemon=True,
+            )
+
+    def _offer(self, item) -> bool:
+        while not self._stop.is_set():
+            try:
+                self._queue.put(item, timeout=0.05)
+                return True
+            except queue_mod.Full:
+                continue
+        return False
+
+    def _produce(self, window_starts):
+        try:
+            for t0 in window_starts:
+                if not self._offer(self._build(t0)):
+                    return
+        except BaseException as e:  # delivered to the consumer's get()
+            self._offer(e)
+
+    def _next_window(self, t0: int):
+        """Inline build when serial, else a queue get whose wait time is
+        exactly the un-hidden host cost (gen_s)."""
+        if self._thread is None:
+            return self._build(t0)
+        item = self._queue.get()
+        if isinstance(item, BaseException):
+            raise item
+        return item
+
+    def __enter__(self):
+        if self._thread is not None:
+            self._thread.start()
+        return self._next_window, self._thread is not None
+
+    def __exit__(self, *exc_info) -> bool:
+        if self._thread is not None:
+            self._stop.set()
+            while self._thread.is_alive():
+                try:
+                    self._queue.get_nowait()
+                except queue_mod.Empty:
+                    pass
+                self._thread.join(timeout=0.05)
+            self._thread.join()
+        return False
+
+
+def _fleet_result(
+    spec: ClusterSpec,
+    cfg: SimConfig,
+    sw: Stopwatch,
+    t_run0: float,
+    *,
+    n_rep: int,
+    T: int,
+    reqs_per_rep: np.ndarray,
+    n_served: int,
+    sat_per_rep: np.ndarray,
+    us_sum_per_rep: np.ndarray,
+    mframe: Optional[MetricsFrame],
+    final_backlog_per_rep: Optional[np.ndarray],
+    mean_compute_inflation: float,
+    n_devices: int,
+    window: int,
+    dispatch_s: float = 0.0,
+    prefetch: int = 0,
+) -> FleetResult:
+    """Every fleet path's tail: per-replication sums to a ``FleetResult``.
+
+    ``gen_s`` is ``fleet/generate_traces`` (the up-front traces and padding
+    pre-pass) plus ``fleet/window_wait``, which wraps the inline build
+    (serial) or the producer-queue get (``prefetch>0``), so it covers the
+    blocking part of every window's arrivals and build; ``timings`` are
+    the run's spans and counters plus ``total_s``; ``mframe`` (the
+    stacked per-(rep, frame) metrics, ``None`` without ``metrics=True``)
+    becomes the ``MetricsResult`` stream."""
+    gen_s = sw.total("fleet/generate_traces", "fleet/window_wait")
+    timings = sw.as_dict()
+    timings["total_s"] = time.perf_counter() - t_run0
+    mres = None
+    if mframe is not None:
+        mres = MetricsResult.from_stacked(
+            mframe,
+            t_ms=(np.arange(T) + 1.0) * cfg.frame_ms,
+            n_edge=spec.n_edge,
+            frame_ms=cfg.frame_ms,
+        )
+    return FleetResult(
+        n_rep=n_rep,
+        n_frames=T,
+        n_requests=int(reqs_per_rep.sum()),
+        n_served=n_served,
+        satisfied_per_rep=100.0 * sat_per_rep / np.maximum(reqs_per_rep, 1),
+        mean_us_per_rep=us_sum_per_rep / np.maximum(reqs_per_rep, 1),
+        final_backlog_per_rep=final_backlog_per_rep,
+        mean_compute_inflation=mean_compute_inflation,
+        n_devices=n_devices,
+        window=window,
+        dispatch_s=dispatch_s,
+        gen_s=gen_s,
+        prefetch=prefetch,
+        timings=timings,
+        metrics=mres,
+    )
+
+
 def simulate_fleet(
     spec: ClusterSpec,
     cfg: SimConfig,
@@ -1633,8 +1285,7 @@ def simulate_fleet(
     (class-level shedding + queue caps, exact on singleton/duplicate
     classes), streaming, windowed arrivals, and metrics; ``devices`` shards
     the class-tensor precompute over the mesh
-    (:func:`_hier_class_tensors`).  ``REPRO_HIER_HOST_LOOP=1`` falls back
-    to the PR-9 host loop for baseline comparisons.
+    (:func:`_hier_class_tensors`).
 
     ``metrics=True`` adds a per-frame :class:`~repro.obs.metrics.MetricsFrame`
     output to the scan — stacked on device across each window, drained with
@@ -1762,9 +1413,7 @@ def simulate_fleet(
     M = spec.n_servers
     use_stream = opts.streaming
     host_side = (not hier) and pol is not None and (not pol.vmappable or not pol.pad)
-    if hier:
-        n_dev = _resolve_fleet_devices(devices, n_rep)
-    elif host_side:
+    if host_side:
         if devices is not None and devices != 1:
             _resolve_fleet_devices(devices, n_rep)  # impossible counts error first
             raise ValueError(
@@ -1781,7 +1430,7 @@ def simulate_fleet(
     # invariance; a materialized trace is bucketed up front either way.
     # The hierarchical path is windowed by construction, so it keeps the
     # stream lazy.
-    lazy = use_stream and W < T and (hier or not host_side)
+    lazy = use_stream and W < T and not host_side
     mode = opts.rng_mode
     prefetch = opts.prefetch
 
@@ -1810,8 +1459,6 @@ def simulate_fleet(
         else:
             n_max = max(src.max_bucket for src in sources)
             n_pad = _pad_bucket(n_max)
-    # trace generation + padding pre-pass; per-window blocking adds to this
-    gen_s = sw.total("fleet/generate_traces")
     # the resilience engine is replication-independent (same network
     # weather for every rep) and frame-indexed, so its traces tile across
     # the rep axis and extend prefix-stable window by window — what keeps
@@ -1824,14 +1471,13 @@ def simulate_fleet(
     if hier:
         return _simulate_fleet_hier(
             spec, cfg, scn, sources, n_rep=n_rep, T=T, W=W, opts=opts,
-            n_dev=n_dev, gen_s=gen_s, engine=engine, metrics=metrics, sw=sw,
-            t_run0=t_run0,
+            n_dev=n_dev, engine=engine, metrics=metrics, sw=sw, t_run0=t_run0,
         )
 
     if host_side:
         return _simulate_fleet_host(
             spec, cfg, scn, pol, sources, n_rep=n_rep, T=T, n_pad=n_pad, seed=seed,
-            gen_s=gen_s, engine=engine, metrics=metrics, sw=sw, t_run0=t_run0,
+            engine=engine, metrics=metrics, sw=sw, t_run0=t_run0,
         )
 
     if pol is not None:
@@ -1974,48 +1620,9 @@ def simulate_fleet(
                 link_rt, up_rt, nreal_rt)
 
     window_starts = list(range(0, T, W))
-    prod_thread = None
-    if prefetch > 0 and len(window_starts) > 0:
-        # bounded producer: builds windows ahead of the consumer, at most
-        # `prefetch` in flight.  Timeout-polling puts let it notice a
-        # consumer that stopped pulling (early exit / error) and unwind.
-        work_q: queue_mod.Queue = queue_mod.Queue(maxsize=prefetch)
-        stop_producer = threading.Event()
-
-        def _offer(item) -> bool:
-            while not stop_producer.is_set():
-                try:
-                    work_q.put(item, timeout=0.05)
-                    return True
-                except queue_mod.Full:
-                    continue
-            return False
-
-        def _produce():
-            try:
-                for t0 in window_starts:
-                    if not _offer(build_window(t0)):
-                        return
-            except BaseException as e:  # delivered to the consumer's get()
-                _offer(e)
-
-        prod_thread = threading.Thread(
-            target=_produce, name="fleet-window-producer", daemon=True
-        )
-        prod_thread.start()
-
-    def next_window(t0: int):
-        """The consumer's pull: inline build when serial, else a queue get
-        whose wait time is exactly the un-hidden host cost (gen_s)."""
-        if prod_thread is None:
-            return build_window(t0)
-        item = work_q.get()
-        if isinstance(item, BaseException):
-            raise item
-        return item
-
     m_acc: Optional[Dict[str, np.ndarray]] = None
-    try:
+    pipeline = _WindowPipeline(build_window, window_starts, prefetch)
+    with pipeline as (next_window, threaded):
         for wi, wi_t0 in enumerate(window_starts):
             with sw.span("fleet/window_wait", CAT_WAIT, window=wi):
                 (t0, t1, Tc, batch, batch_rt, n_real, tq_flat,
@@ -2116,57 +1723,29 @@ def simulate_fleet(
                     for f in MetricsFrame._fields:
                         m_acc[f][:, t0:t1] = getattr(mfw, f)
 
-    finally:
-        if prod_thread is not None:
-            # early exit or error: unblock the producer (it polls the stop
-            # event between put attempts), drain whatever it queued, join
-            stop_producer.set()
-            while prod_thread.is_alive():
-                try:
-                    work_q.get_nowait()
-                except queue_mod.Empty:
-                    pass
-                prod_thread.join(timeout=0.05)
-            prod_thread.join()
-
     if executor is not None:
         executor.shutdown(wait=False)
     final_backlog = np.concatenate(
         [np.asarray(c.backlog_gamma) for c in carries]
     )[:n_rep]
-    reqs_per_rep = n_real_frames.sum(1)
-    sat_per_rep = sat_frames.sum(1)
     # mean_us averages over n_pad rows (padded rows contribute 0); recover the
     # per-rep sum (exact: n_pad is a power of two) and renormalize by the
     # rep's true request count
-    us_sum_per_rep = (us_frames * n_pad).sum(1)
-    gen_s += sw.total("fleet/window_wait")
-    timings = sw.as_dict()
-    timings["total_s"] = time.perf_counter() - t_run0
-    mres = None
-    if metrics and m_acc is not None:
-        mres = MetricsResult.from_stacked(
-            MetricsFrame(**m_acc),
-            t_ms=(np.arange(T) + 1.0) * cfg.frame_ms,
-            n_edge=spec.n_edge,
-            frame_ms=cfg.frame_ms,
-        )
-    return FleetResult(
+    return _fleet_result(
+        spec, cfg, sw, t_run0,
         n_rep=n_rep,
-        n_frames=T,
-        n_requests=int(reqs_per_rep.sum()),
+        T=T,
+        reqs_per_rep=n_real_frames.sum(1),
         n_served=int(served_frames.sum()),
-        satisfied_per_rep=100.0 * sat_per_rep / np.maximum(reqs_per_rep, 1),
-        mean_us_per_rep=us_sum_per_rep / np.maximum(reqs_per_rep, 1),
+        sat_per_rep=sat_frames.sum(1),
+        us_sum_per_rep=(us_frames * n_pad).sum(1),
+        mframe=MetricsFrame(**m_acc) if metrics and m_acc is not None else None,
         final_backlog_per_rep=final_backlog if ccfg.enabled else None,
         mean_compute_inflation=float(np.mean(phi_frames)) if ccfg.enabled else 1.0,
         n_devices=n_dev,
         window=W,
         dispatch_s=sw.total("fleet/dispatch"),
-        gen_s=gen_s,
-        prefetch=prefetch if prod_thread is not None else 0,
-        timings=timings,
-        metrics=mres,
+        prefetch=prefetch if threaded else 0,
     )
 
 
@@ -2181,11 +1760,10 @@ def _simulate_fleet_host(
     T: int,
     n_pad: int,
     seed: int,
-    gen_s: float = 0.0,
-    engine: Optional[ResilienceEngine] = None,
-    metrics: bool = False,
-    sw: Optional[Stopwatch] = None,
-    t_run0: Optional[float] = None,
+    engine: Optional[ResilienceEngine],
+    metrics: bool,
+    sw: Stopwatch,
+    t_run0: float,
 ) -> FleetResult:
     """Host-side fleet path for non-vmappable / non-padding policies (the
     ILP / LP-bound oracles): schedule each *unpadded* frame in a Python
@@ -2196,10 +1774,6 @@ def _simulate_fleet_host(
     ccfg = cfg.congestion
     acfg = cfg.admission
     M = spec.n_servers
-    if sw is None:
-        sw = Stopwatch()
-    if t_run0 is None:
-        t_run0 = time.perf_counter()
     fleet_frames: List[List[Request]] = []
     with sw.span("fleet/arrivals", CAT_GEN):
         for src in sources:
@@ -2350,7 +1924,7 @@ def _simulate_fleet_host(
     served = (np.asarray(assign.j) >= 0) & real
     sat = sat & real
 
-    mres = None
+    mframe = None
     if metrics:
         # vectorized post-pass over the padded grid — same definitions as
         # the scan's frame_metrics rows (served/sat masked to real rows,
@@ -2378,46 +1952,35 @@ def _simulate_fleet_host(
             def rt(x):
                 return x.reshape((n_rep, T) + x.shape[1:])
 
-            mres = MetricsResult.from_stacked(
-                MetricsFrame(
-                    n_arrivals=rt(n_real.astype(np.int32)),
-                    n_served=rt(served.sum(-1).astype(np.int32)),
-                    n_satisfied=rt(sat.sum(-1).astype(np.int32)),
-                    n_shed=rt(m_shed),
-                    n_refused=rt(m_refused),
-                    tier_hist=rt(tier),
-                    qos_sat=rt(qos_sat),
-                    qos_count=rt(qos_cnt),
-                    util_gamma=rt(ug.astype(np.float32)),
-                    util_eta=rt(ue.astype(np.float32)),
-                    backlog_gamma=rt(m_bg),
-                    backlog_eta=rt(m_be),
-                    us_sum=rt((us * n_pad).astype(np.float32)),
-                ),
-                t_ms=(np.arange(T) + 1.0) * cfg.frame_ms,
-                n_edge=spec.n_edge,
-                frame_ms=cfg.frame_ms,
+            mframe = MetricsFrame(
+                n_arrivals=rt(n_real.astype(np.int32)),
+                n_served=rt(served.sum(-1).astype(np.int32)),
+                n_satisfied=rt(sat.sum(-1).astype(np.int32)),
+                n_shed=rt(m_shed),
+                n_refused=rt(m_refused),
+                tier_hist=rt(tier),
+                qos_sat=rt(qos_sat),
+                qos_count=rt(qos_cnt),
+                util_gamma=rt(ug.astype(np.float32)),
+                util_eta=rt(ue.astype(np.float32)),
+                backlog_gamma=rt(m_bg),
+                backlog_eta=rt(m_be),
+                us_sum=rt((us * n_pad).astype(np.float32)),
             )
 
-    timings = sw.as_dict()
-    timings["total_s"] = time.perf_counter() - t_run0
-    reqs_per_rep = n_real.reshape(n_rep, T).sum(1)
-    sat_per_rep = sat.reshape(n_rep, T, n_pad).sum((1, 2))
-    us_sum_per_rep = (us * n_pad).reshape(n_rep, T).sum(1)
-    return FleetResult(
+    return _fleet_result(
+        spec, cfg, sw, t_run0,
         n_rep=n_rep,
-        n_frames=T,
-        n_requests=int(reqs_per_rep.sum()),
+        T=T,
+        reqs_per_rep=n_real.reshape(n_rep, T).sum(1),
         n_served=int(served.sum()),
-        satisfied_per_rep=100.0 * sat_per_rep / np.maximum(reqs_per_rep, 1),
-        mean_us_per_rep=us_sum_per_rep / np.maximum(reqs_per_rep, 1),
+        sat_per_rep=sat.reshape(n_rep, T, n_pad).sum((1, 2)),
+        us_sum_per_rep=(us * n_pad).reshape(n_rep, T).sum(1),
+        mframe=mframe,
         final_backlog_per_rep=final_backlog if ccfg.enabled else None,
         mean_compute_inflation=float(np.mean(phi_c)) if ccfg.enabled else 1.0,
         n_devices=1,
         window=T,
-        gen_s=gen_s,
-        timings=timings,
-        metrics=mres,
     )
 
 
@@ -2605,12 +2168,11 @@ def _simulate_fleet_hier(
     T: int,
     W: int,
     opts: EngineOptions,
-    n_dev: int = 1,
-    gen_s: float = 0.0,
-    engine: Optional[ResilienceEngine] = None,
-    metrics: bool = False,
-    sw: Optional[Stopwatch] = None,
-    t_run0: Optional[float] = None,
+    n_dev: int,
+    engine: Optional[ResilienceEngine],
+    metrics: bool,
+    sw: Stopwatch,
+    t_run0: float,
 ) -> FleetResult:
     """Class-aggregate fleet path for ``EngineOptions(scheduler="hierarchical")``.
 
@@ -2624,9 +2186,7 @@ def _simulate_fleet_hier(
     *inside* the same vmap-over-R / ``lax.scan``-over-T / prefetch pipeline
     as the dense path, with the congestion backlog as the scan carry and
     class-level admission control (deadline shedding + queue caps) inside
-    the jitted step.  ``REPRO_HIER_HOST_LOOP=1`` routes to the retained
-    PR-9 per-window host loop (:func:`_simulate_fleet_hier_host`), the
-    baseline the scaling benchmark compares against.
+    the jitted step.
 
     Satisfaction is accounted **per member** on the host after each window:
     the fixed-shape ``(take, start)`` cells deaggregate deterministically
@@ -2638,11 +2198,6 @@ def _simulate_fleet_hier(
     never the accounting.  Memory and schedule time still scale with the
     class count, which is what sustains 10^5+ users per frame.
     """
-    if os.environ.get("REPRO_HIER_HOST_LOOP", "0") not in ("0", "", "false", "False"):
-        return _simulate_fleet_hier_host(
-            spec, cfg, scn, sources, n_rep=n_rep, T=T, W=W, gen_s=gen_s,
-            engine=engine, metrics=metrics, sw=sw, t_run0=t_run0,
-        )
     from .aggregation import QuantizationConfig, aggregate_requests, hier_backend_fn
 
     ccfg = cfg.congestion
@@ -2650,10 +2205,6 @@ def _simulate_fleet_hier(
     M = spec.n_servers
     n_edge = spec.n_edge
     prefetch = opts.prefetch
-    if sw is None:
-        sw = Stopwatch()
-    if t_run0 is None:
-        t_run0 = time.perf_counter()
     quant = QuantizationConfig()
     edges_q = np.asarray(QOS_ACC_EDGES, np.float64)
     nq = len(QOS_ACC_EDGES) + 1
@@ -2845,45 +2396,11 @@ def _simulate_fleet_hier(
         return (t0, t1, Tc, batch_rt, us_rt, feas_rt, tq_rt, cnt_rt, infos,
                 gb, eb, links_by_k, n_arr)
 
-    window_starts = list(range(0, T, W))
-    prod_thread = None
-    if prefetch > 0 and len(window_starts) > 0:
-        work_q: queue_mod.Queue = queue_mod.Queue(maxsize=prefetch)
-        stop_producer = threading.Event()
-
-        def _offer(item) -> bool:
-            while not stop_producer.is_set():
-                try:
-                    work_q.put(item, timeout=0.05)
-                    return True
-                except queue_mod.Full:
-                    continue
-            return False
-
-        def _produce():
-            try:
-                for t0 in window_starts:
-                    if not _offer(build_window(t0)):
-                        return
-            except BaseException as e:  # delivered to the consumer's get()
-                _offer(e)
-
-        prod_thread = threading.Thread(
-            target=_produce, name="fleet-hier-producer", daemon=True
-        )
-        prod_thread.start()
-
-    def next_window(t0: int):
-        if prod_thread is None:
-            return build_window(t0)
-        item = work_q.get()
-        if isinstance(item, BaseException):
-            raise item
-        return item
-
     carry = (jnp.zeros((n_rep, M), jnp.float32), jnp.zeros((n_rep, M), jnp.float32))
     bw_true = spec.bandwidth_true
-    try:
+    window_starts = list(range(0, T, W))
+    pipeline = _WindowPipeline(build_window, window_starts, prefetch)
+    with pipeline as (next_window, threaded):
         def _post_window(wi, t0, Tc, infos, gb, eb, links_by_k, n_arr, outs):
             """Host-side accounting for one dispatched window.  Called one
             window *behind* the dispatch loop: ``outs`` are still-async
@@ -3011,301 +2528,24 @@ def _simulate_fleet_hier(
             pending = (wi, t0, Tc, infos, gb, eb, links_by_k, n_arr, outs)
         if pending is not None:
             _post_window(*pending)
-    finally:
-        if prod_thread is not None:
-            stop_producer.set()
-            while prod_thread.is_alive():
-                try:
-                    work_q.get_nowait()
-                except queue_mod.Empty:
-                    pass
-                prod_thread.join(timeout=0.05)
-            prod_thread.join()
 
-    final_bg = np.asarray(carry[0])
-    # window_wait wraps the inline build (serial) or the producer-queue get
-    # (prefetch>0), so it already covers arrivals + aggregation blocking
-    gen_s += sw.total("fleet/window_wait")
-    timings = sw.as_dict()
-    timings["total_s"] = time.perf_counter() - t_run0
-    mres = None
-    if metrics:
-        mres = MetricsResult.from_stacked(
-            MetricsFrame(**m_acc),
-            t_ms=(np.arange(T) + 1.0) * cfg.frame_ms,
-            n_edge=spec.n_edge,
-            frame_ms=cfg.frame_ms,
-        )
-    return FleetResult(
+    return _fleet_result(
+        spec, cfg, sw, t_run0,
         n_rep=n_rep,
-        n_frames=T,
-        n_requests=int(reqs_per_rep.sum()),
+        T=T,
+        reqs_per_rep=reqs_per_rep,
         n_served=int(served_per_rep.sum()),
-        satisfied_per_rep=100.0 * sat_per_rep / np.maximum(reqs_per_rep, 1),
-        mean_us_per_rep=us_sum_per_rep / np.maximum(reqs_per_rep, 1),
-        final_backlog_per_rep=final_bg if ccfg.enabled else None,
+        sat_per_rep=sat_per_rep,
+        us_sum_per_rep=us_sum_per_rep,
+        mframe=MetricsFrame(**m_acc) if metrics else None,
+        final_backlog_per_rep=np.asarray(carry[0]) if ccfg.enabled else None,
         mean_compute_inflation=(
             phi_sum / phi_cnt if ccfg.enabled and phi_cnt else 1.0
         ),
         n_devices=n_dev,
         window=W,
         dispatch_s=sw.total("fleet/dispatch"),
-        gen_s=gen_s,
-        prefetch=prefetch if prod_thread is not None else 0,
-        timings=timings,
-        metrics=mres,
-    )
-
-
-def _simulate_fleet_hier_host(
-    spec: ClusterSpec,
-    cfg: SimConfig,
-    scn: Scenario,
-    sources: List[_RepFrameSource],
-    *,
-    n_rep: int,
-    T: int,
-    W: int,
-    gen_s: float = 0.0,
-    engine: Optional[ResilienceEngine] = None,
-    metrics: bool = False,
-    sw: Optional[Stopwatch] = None,
-    t_run0: Optional[float] = None,
-) -> FleetResult:
-    """The PR-9 per-window *host loop* for the class-aggregate fleet, kept
-    as the device pipeline's reference baseline (``REPRO_HIER_HOST_LOOP=1``
-    routes here; ``benchmarks/fleet_scale.py`` uses it for the wall-time
-    comparison).  Satisfaction is accounted *class-level* from the
-    count-weighted representatives, admission control is not evaluated, and
-    scheduling runs one frame at a time on the host — the three things
-    :func:`_simulate_fleet_hier` fixes.
-
-    Congestion mirrors the scan step in the same order: the scheduler sees
-    the backlog-reduced budgets, inflation factors come from committed +
-    carried load against the *full* budgets, realized completion times are
-    inflated per :func:`repro.core.queueing.congested_ctime`'s formula at
-    the chosen cells, and the backlog drains every frame.  Arrivals stream
-    window by window (``W`` frames at a time), so long horizons stay
-    bounded-memory end to end.
-    """
-    from .aggregation import AggregateClasses, QuantizationConfig, aggregate_requests, hier_assign
-
-    ccfg = cfg.congestion
-    M = spec.n_servers
-    n_edge = spec.n_edge
-    if sw is None:
-        sw = Stopwatch()
-    if t_run0 is None:
-        t_run0 = time.perf_counter()
-    quant = QuantizationConfig()
-    edges_q = np.asarray(QOS_ACC_EDGES, np.float64)
-    nq = len(QOS_ACC_EDGES) + 1
-
-    reqs_per_rep = np.zeros(n_rep, np.int64)
-    served_per_rep = np.zeros(n_rep, np.int64)
-    sat_per_rep = np.zeros(n_rep, np.int64)
-    us_sum_per_rep = np.zeros(n_rep, np.float64)
-    bg = np.zeros((n_rep, M))  # carried compute backlog, f64 like the budgets
-    be = np.zeros((n_rep, M))
-    phi_sum = 0.0
-    phi_cnt = 0
-    m_acc: Optional[Dict[str, np.ndarray]] = None
-    if metrics:
-        m_acc = {
-            "n_arrivals": np.zeros((n_rep, T), np.int32),
-            "n_served": np.zeros((n_rep, T), np.int32),
-            "n_satisfied": np.zeros((n_rep, T), np.int32),
-            "n_shed": np.zeros((n_rep, T), np.int32),
-            "n_refused": np.zeros((n_rep, T), np.int32),
-            "tier_hist": np.zeros((n_rep, T, 3), np.int32),
-            "qos_sat": np.zeros((n_rep, T, nq), np.int32),
-            "qos_count": np.zeros((n_rep, T, nq), np.int32),
-            "util_gamma": np.zeros((n_rep, T, M), np.float32),
-            "util_eta": np.zeros((n_rep, T, M), np.float32),
-            "backlog_gamma": np.zeros((n_rep, T, M), np.float32),
-            "backlog_eta": np.zeros((n_rep, T, M), np.float32),
-            "us_sum": np.zeros((n_rep, T), np.float32),
-        }
-
-    for t0 in range(0, T, W):
-        t1 = min(t0 + W, T)
-        Tc = t1 - t0
-        with sw.span("fleet/hier_build", CAT_BUILD, t0=t0):
-            gb, eb = _frame_budgets_batch(
-                spec, cfg, scn, (t0 + np.arange(Tc)) * cfg.frame_ms, engine=engine,
-            )
-            links = (
-                [engine.link_frame(t0 + k) for k in range(Tc)]
-                if engine is not None else None
-            )
-        for rep, src in enumerate(sources):
-            with sw.span("fleet/arrivals", CAT_GEN, t0=t0, rep=rep):
-                buckets = src.take(t1)
-            for k, bucket in enumerate(buckets):
-                tf = t0 + k
-                n = len(bucket)
-                reqs_per_rep[rep] += n
-                frame_end = (tf + 1) * cfg.frame_ms
-                g_full, e_full = gb[k], eb[k]
-                w_load = np.zeros(M)
-                c_load = np.zeros(M)
-                chunks = np.zeros((0, 4), np.int64)
-                if n:
-                    if isinstance(bucket, RequestColumns):
-                        cov, svc = bucket.cover, bucket.service
-                        A_r, C_r = bucket.A, bucket.C
-                        size = bucket.size_bytes
-                        arr_ms = bucket.arrival_ms
-                    else:
-                        cov = np.array([r.cover for r in bucket], np.int64)
-                        svc = np.array([r.service for r in bucket], np.int64)
-                        A_r = np.array([r.A for r in bucket], np.float64)
-                        C_r = np.array([r.C for r in bucket], np.float64)
-                        size = np.array([r.size_bytes for r in bucket], np.float64)
-                        arr_ms = np.array([r.arrival_ms for r in bucket], np.float64)
-                    with sw.span("fleet/hier_aggregate", CAT_BUILD, frame=tf):
-                        tq = frame_end - np.asarray(arr_ms, np.float64)
-                        count, first_idx, members, offsets, repc = (
-                            aggregate_requests(cov, svc, A_r, C_r, size, tq, quant)
-                        )
-                        rc = RequestColumns(
-                            arrival_ms=frame_end - repc["tq"],
-                            cover=repc["cover"],
-                            service=repc["service"],
-                            A=repc["A"],
-                            C=repc["C"],
-                            size_bytes=repc["size"],
-                        )
-                        link = None
-                        if links is not None:
-                            sc, la = links[k]
-                            link = (sc[repc["cover"]], la[repc["cover"]])
-                        if ccfg.enabled:  # scheduler sees effective capacity
-                            g_sched = np.maximum(g_full - bg[rep], 0.0)
-                            e_sched = np.maximum(e_full - be[rep], 0.0)
-                        else:
-                            g_sched, e_sched = g_full, e_full
-                        cls_inst = _build_frame_instance(
-                            rc, spec, cfg, frame_end, spec.bandwidth_true,
-                            cfg.max_cs, gamma=g_sched, eta=e_sched, link=link,
-                        )
-                        agg = AggregateClasses(
-                            count=count, first_idx=first_idx, members=members,
-                            offsets=offsets, cover=repc["cover"],
-                            us=np.asarray(us_tensor(cls_inst)),
-                            feas=np.asarray(hard_feasible(cls_inst)),
-                            v=np.asarray(cls_inst.v),
-                            u=np.asarray(cls_inst.u),
-                        )
-                    with sw.span("fleet/schedule_hier", CAT_SCHED, frame=tf):
-                        chunks = hier_assign(agg, g_sched, e_sched, exact=False)
-                    if len(chunks):
-                        cc, jj, ll, take = (chunks[:, i] for i in range(4))
-                        vv = agg.v[cc, jj, ll].astype(np.float64)
-                        uu = agg.u[cc, jj, ll].astype(np.float64)
-                        np.add.at(w_load, jj, take * vv)
-                        off_m = jj != agg.cover[cc]
-                        if off_m.any():
-                            np.add.at(
-                                c_load, agg.cover[cc][off_m], (take * uu)[off_m]
-                            )
-                # inflation from committed + carried load vs the FULL budgets
-                # (the scan's order: schedule, commit, inflate, drain)
-                if ccfg.enabled:
-                    phi_c = np.asarray(
-                        compute_inflation(bg[rep] + w_load, g_full, ccfg), np.float64
-                    )
-                    phi_e = np.asarray(
-                        comm_inflation(be[rep] + c_load, e_full, ccfg), np.float64
-                    )
-                    phi_sum += float(phi_c.sum())
-                    phi_cnt += M
-                if len(chunks):
-                    ct = np.asarray(cls_inst.ctime, np.float64)[cc, jj, ll]
-                    acc_c = np.asarray(cls_inst.acc, np.float64)[cc, jj, ll]
-                    if ccfg.enabled:  # congested_ctime's formula, class-level
-                        comm = ct - vv - repc["tq"][cc]
-                        ct = (
-                            ct
-                            + vv * (phi_c[jj] - 1.0)
-                            + comm * (phi_e[agg.cover[cc]] - 1.0)
-                        )
-                    A_c = repc["A"][cc]
-                    C_c = repc["C"][cc]
-                    sat_c = (acc_c >= A_c) & (ct <= C_c)
-                    us_c = (
-                        cfg.w_a * (acc_c - A_c) / cfg.max_as
-                        + cfg.w_c * (C_c - ct) / cfg.max_cs
-                    )
-                    served_per_rep[rep] += int(take.sum())
-                    sat_per_rep[rep] += int((take * sat_c).sum())
-                    us_sum_per_rep[rep] += float((take * us_c).sum())
-                if ccfg.enabled:  # backlog conservation: see step_backlog
-                    bg[rep] = np.maximum(
-                        bg[rep] + w_load - g_full * ccfg.drain, 0.0
-                    )
-                    be[rep] = np.maximum(
-                        be[rep] + c_load - e_full * ccfg.drain, 0.0
-                    )
-                if metrics:
-                    m_acc["n_arrivals"][rep, tf] = n
-                    if n:
-                        cls_q = (repc["A"][:, None] >= edges_q).sum(-1)
-                        np.add.at(m_acc["qos_count"][rep, tf], cls_q, count)
-                    if len(chunks):
-                        m_acc["n_served"][rep, tf] = int(take.sum())
-                        m_acc["n_satisfied"][rep, tf] = int((take * sat_c).sum())
-                        local_m = jj == agg.cover[cc]
-                        cloud_m = (jj >= n_edge) & ~local_m
-                        eo_m = ~local_m & ~cloud_m
-                        m_acc["tier_hist"][rep, tf] = (
-                            int(take[local_m].sum()),
-                            int(take[eo_m].sum()),
-                            int(take[cloud_m].sum()),
-                        )
-                        np.add.at(
-                            m_acc["qos_sat"][rep, tf], cls_q[cc],
-                            (take * sat_c).astype(np.int64),
-                        )
-                        m_acc["us_sum"][rep, tf] = float((take * us_c).sum())
-                    with np.errstate(invalid="ignore"):
-                        m_acc["util_gamma"][rep, tf] = np.where(
-                            g_full > 0.0, w_load / np.maximum(g_full, 1e-9), 0.0
-                        )
-                        m_acc["util_eta"][rep, tf] = np.where(
-                            e_full > 0.0, c_load / np.maximum(e_full, 1e-9), 0.0
-                        )
-                    m_acc["backlog_gamma"][rep, tf] = bg[rep]
-                    m_acc["backlog_eta"][rep, tf] = be[rep]
-
-    gen_s += sw.total("fleet/arrivals")
-    timings = sw.as_dict()
-    timings["total_s"] = time.perf_counter() - t_run0
-    mres = None
-    if metrics:
-        mres = MetricsResult.from_stacked(
-            MetricsFrame(**m_acc),
-            t_ms=(np.arange(T) + 1.0) * cfg.frame_ms,
-            n_edge=spec.n_edge,
-            frame_ms=cfg.frame_ms,
-        )
-    return FleetResult(
-        n_rep=n_rep,
-        n_frames=T,
-        n_requests=int(reqs_per_rep.sum()),
-        n_served=int(served_per_rep.sum()),
-        satisfied_per_rep=100.0 * sat_per_rep / np.maximum(reqs_per_rep, 1),
-        mean_us_per_rep=us_sum_per_rep / np.maximum(reqs_per_rep, 1),
-        final_backlog_per_rep=bg.astype(np.float32) if ccfg.enabled else None,
-        mean_compute_inflation=(
-            phi_sum / phi_cnt if ccfg.enabled and phi_cnt else 1.0
-        ),
-        n_devices=1,
-        window=W,
-        dispatch_s=sw.total("fleet/schedule_hier"),
-        gen_s=gen_s,
-        timings=timings,
-        metrics=mres,
+        prefetch=prefetch if threaded else 0,
     )
 
 

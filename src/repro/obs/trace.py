@@ -27,7 +27,8 @@ nothing is allocated, and no lock is taken but a :class:`Stopwatch`'s
 boundaries; an active recorder gets a ``"C"`` event for each.
 
 Events carry the recording thread's id and name, so spans from
-``simulate_fleet``'s producer thread ("fleet-window-producer") and the
+``simulate_fleet``'s producer thread ("fleet-window-producer", the one
+window pipeline of the dense and the hierarchical fleet) and the
 async JSONL exporter land on their own tracks in ``chrome://tracing`` /
 Perfetto.  The emitted JSON object format is::
 

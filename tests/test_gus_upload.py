@@ -25,7 +25,7 @@ from repro.core.instance import (  # noqa: E402
     generate_instance,
     pad_instance,
 )
-from repro.core.simulator import _pad_bucket  # noqa: E402
+from repro.core.frames import _pad_bucket  # noqa: E402
 
 RELAX = [(False, False), (True, False), (False, True)]
 RELAX_IDS = ["exact", "relax_compute", "relax_comm"]

@@ -478,27 +478,6 @@ def test_fleet_xla_vs_pallas_bitwise():
         assert xa[key] == pa[key], key
 
 
-def test_device_path_matches_host_loop_fallback(monkeypatch):
-    """REPRO_HIER_HOST_LOOP=1 resurrects the PR-9 host loop; on a
-    singleton-class scenario with everything off, the two pipelines
-    agree on the integer accounting."""
-    cfg = fleet_cfg()
-    device = simulate_fleet(
-        SPEC, cfg, policy="gus", n_rep=2, seed=0,
-        options=EngineOptions(scheduler="hierarchical"),
-    )
-    monkeypatch.setenv("REPRO_HIER_HOST_LOOP", "1")
-    host = simulate_fleet(
-        SPEC, cfg, policy="gus", n_rep=2, seed=0,
-        options=EngineOptions(scheduler="hierarchical"),
-    )
-    assert device.n_requests == host.n_requests
-    assert device.n_served == host.n_served
-    np.testing.assert_array_equal(
-        np.asarray(device.satisfied_per_rep),
-        np.asarray(host.satisfied_per_rep))
-
-
 def test_mega_city_with_admission_and_impairments():
     """The previously-impossible composition: city-scale hierarchical
     fleet with admission and impairments both enabled."""

@@ -11,6 +11,7 @@ pytest.importorskip("jax")
 
 from repro.core import (  # noqa: E402
     ArrivalStream,
+    EngineOptions,
     SimConfig,
     demo_cluster_spec,
     get_scenario,
@@ -240,66 +241,89 @@ def _producer_threads():
     ]
 
 
-def test_producer_exception_propagates_without_hang():
+def _explode_on_second_call(fn, message):
+    calls = {"n": 0}
+
+    def exploding(*args, **kw):
+        calls["n"] += 1
+        if calls["n"] >= 2:  # let window 0 through, fail while overlapped
+            raise RuntimeError(message)
+        return fn(*args, **kw)
+
+    return exploding
+
+
+#: both device fleets run their windows through the same producer thread
+PIPELINE_SCHEDULERS = ["dense", "hierarchical"]
+
+
+@pytest.mark.parametrize("scheduler", PIPELINE_SCHEDULERS)
+def test_producer_exception_propagates_without_hang(scheduler, monkeypatch):
     """An exception inside the host-side window builder must surface to the
     caller (not deadlock the queue) and leave no producer thread behind."""
     import repro.core.simulator as sim_mod
 
     spec = demo_cluster_spec()
-    real_build = sim_mod._build_frame_batch
-    calls = {"n": 0}
-
-    def exploding_build(*args, **kw):
-        calls["n"] += 1
-        if calls["n"] >= 2:  # let window 0 through, fail while overlapped
-            raise RuntimeError("boom in host builder")
-        return real_build(*args, **kw)
-
-    sim_mod._build_frame_batch = exploding_build
-    try:
-        with pytest.raises(RuntimeError, match="boom in host builder"):
-            simulate_fleet(spec, cfg(), policy="gus", n_rep=2, seed=0,
-                           window=2, prefetch=2)
-    finally:
-        sim_mod._build_frame_batch = real_build
+    monkeypatch.setattr(sim_mod, "_build_frame_batch", _explode_on_second_call(
+        sim_mod._build_frame_batch, "boom in host builder"))
+    with pytest.raises(RuntimeError, match="boom in host builder"):
+        simulate_fleet(spec, cfg(), policy="gus", n_rep=2, seed=0,
+                       options=EngineOptions(scheduler=scheduler, window=2,
+                                             prefetch=2))
     for t in _producer_threads():
         t.join(timeout=5.0)
     assert not [t for t in _producer_threads() if t.is_alive()]
 
 
-def test_consumer_error_drains_producer_and_joins():
+@pytest.mark.parametrize("scheduler", PIPELINE_SCHEDULERS)
+def test_consumer_error_drains_producer_and_joins(scheduler, monkeypatch):
     """If the *consumer* dies mid-run (device-side error), the early exit
-    must drain the bounded queue so the producer unblocks and joins."""
+    must drain the bounded queue so the producer unblocks and joins.  The
+    dense consumer fails in its window accounting (``satisfied_mask``), the
+    hierarchical one in its second call of the jitted runner."""
     import repro.core.simulator as sim_mod
 
     spec = demo_cluster_spec()
-    real_mask = sim_mod.satisfied_mask
-    calls = {"n": 0}
-
-    def exploding_mask(*args, **kw):
-        calls["n"] += 1
-        if calls["n"] >= 2:
-            raise RuntimeError("boom in consumer")
-        return real_mask(*args, **kw)
-
-    sim_mod.satisfied_mask = exploding_mask
-    try:
-        with pytest.raises(RuntimeError, match="boom in consumer"):
-            # depth-1 queue + tiny windows: the producer is guaranteed to be
-            # blocked in put() when the consumer raises
-            simulate_fleet(spec, cfg(), policy="gus", n_rep=2, seed=0,
-                           window=1, prefetch=1)
-    finally:
-        sim_mod.satisfied_mask = real_mask
+    if scheduler == "dense":
+        monkeypatch.setattr(sim_mod, "satisfied_mask", _explode_on_second_call(
+            sim_mod.satisfied_mask, "boom in consumer"))
+    else:
+        real_impl = sim_mod._hier_runner_impl
+        monkeypatch.setattr(
+            sim_mod, "_hier_runner_impl",
+            lambda *a: _explode_on_second_call(real_impl(*a), "boom in consumer"),
+        )
+    with pytest.raises(RuntimeError, match="boom in consumer"):
+        # depth-1 queue + tiny windows: the producer is guaranteed to be
+        # blocked in put() when the consumer raises
+        simulate_fleet(spec, cfg(), policy="gus", n_rep=2, seed=0,
+                       options=EngineOptions(scheduler=scheduler, window=1,
+                                             prefetch=1))
     for t in _producer_threads():
         t.join(timeout=5.0)
     assert not [t for t in _producer_threads() if t.is_alive()]
 
 
-def test_no_producer_thread_leak_on_success():
+@pytest.mark.parametrize("scheduler", PIPELINE_SCHEDULERS)
+def test_no_producer_thread_leak_on_success(scheduler, monkeypatch):
+    import repro.core.simulator as sim_mod
+
     spec = demo_cluster_spec()
+    real_build = sim_mod._build_frame_batch
+    build_threads = []
+
+    def recording_build(*args, **kw):
+        build_threads.append(threading.current_thread().name)
+        return real_build(*args, **kw)
+
+    monkeypatch.setattr(sim_mod, "_build_frame_batch", recording_build)
     before = len([t for t in _producer_threads() if t.is_alive()])
-    simulate_fleet(spec, cfg(), policy="gus", n_rep=2, seed=0, window=2, prefetch=2)
+    fr = simulate_fleet(spec, cfg(), policy="gus", n_rep=2, seed=0,
+                        options=EngineOptions(scheduler=scheduler, window=2,
+                                              prefetch=2))
+    assert fr.prefetch == 2
+    # every window was built on the producer thread, under its one name
+    assert build_threads and set(build_threads) == {"fleet-window-producer"}
     assert len([t for t in _producer_threads() if t.is_alive()]) == before
 
 

@@ -506,7 +506,7 @@ def test_fleet_counters_match_shapes():
     computed here from shapes and dtypes (3 reps in groups of 2, so one
     padded replication; 3 frames in windows of 2)."""
     from repro.core import EngineOptions
-    from repro.core.simulator import _pad_bucket
+    from repro.core.frames import _pad_bucket
 
     c = cfg(horizon_ms=9000.0)
     fr = simulate_fleet(SPEC, c, n_rep=3, seed=0,
