@@ -17,7 +17,14 @@ import numpy as np
 
 from .impairments import ResilienceEngine
 from .instance import FlatInstance
-from .scenarios import Request, RequestColumns, Scenario, bucket_arrivals, bucket_columns
+from .scenarios import (
+    Request,
+    RequestColumns,
+    Scenario,
+    _scalar_hook_is_newer,
+    bucket_arrivals,
+    bucket_columns,
+)
 from .streaming import ArrivalStream, stream_trace, stream_trace_columns
 
 if TYPE_CHECKING:
@@ -439,19 +446,23 @@ class _RepFrameSource:
     """One replication's per-frame request buckets, materialized or lazy.
 
     *Materialized* reproduces the legacy fleet generation bit-for-bit: one
-    ``default_rng(seed + rep)`` drives ``generate_arrivals`` (or the trace
+    ``default_rng(seed + rep)`` drives ``generate_arrivals_columns`` (or the trace
     comes from :func:`stream_trace`) and then the per-frame mobility draws.
     *Lazy* holds an :class:`ArrivalStream` and draws each frame's bucket on
     demand, so a windowed fleet never materializes more than one window of
     requests — the stream's chunking invariance makes the buckets (and the
     mobility draw order) identical either way.
 
-    In ``rng_mode="vectorized"`` the materialized trace stays columnar
-    (:class:`RequestColumns` buckets) end to end — the grid builder fills
-    frames from array slices and per-request Python objects never exist;
-    the lazy stream uses the chunk-buffered vectorized engine.  Columnar
-    and lazy buckets carry the same values for the same seed (one chunk
-    code path underneath), so windowed==materialized holds in both modes.
+    The materialized trace from the replication's own generator stays
+    columnar (:class:`RequestColumns` buckets) end to end in both RNG
+    modes — the grid builder fills frames from array slices and
+    per-request Python objects never exist.  Two traces stay
+    :class:`Request` lists: the paper-default stream's, and that of a
+    scenario overriding :meth:`~repro.core.scenarios.Scenario.generate_arrivals`
+    (its objects are its trace).  In ``rng_mode="vectorized"`` the lazy
+    stream uses the chunk-buffered vectorized engine; columnar and lazy
+    buckets carry the same values for the same seed (one chunk code path
+    underneath), so windowed==materialized holds in both modes.
     """
 
     def __init__(
@@ -464,23 +475,30 @@ class _RepFrameSource:
         self.rng = np.random.default_rng(rep_seed)
         self.stream: Optional[ArrivalStream] = None
         self.buckets = None  # List[List[Request]] | List[RequestColumns]
-        vectorized = rng_mode == "vectorized"
         if lazy:
             self.stream = ArrivalStream(
                 scn, rep_seed, n_edge, n_services, cfg, rng_mode=rng_mode
             )
-        elif vectorized:
-            if use_stream:
+        elif use_stream:
+            if rng_mode == "vectorized":
                 cols = stream_trace_columns(scn, rep_seed, n_edge, n_services, cfg)
+                self.buckets = bucket_columns(cols, cfg.frame_ms, T)
             else:
-                cols = scn.generate_arrivals_columns(self.rng, n_edge, n_services, cfg)
-            self.buckets = bucket_columns(cols, cfg.frame_ms, T)
-        else:
-            if use_stream:
                 reqs = stream_trace(scn, rep_seed, n_edge, n_services, cfg)
-            else:
-                reqs = scn.generate_arrivals(self.rng, n_edge, n_services, cfg)
+                self.buckets = bucket_arrivals(reqs, cfg.frame_ms, T)
+        elif _scalar_hook_is_newer(
+            type(scn), "generate_arrivals", "generate_arrivals_columns"
+        ):
+            # a scenario that reshapes the object trace is honoured as such
+            reqs = scn.generate_arrivals(
+                self.rng, n_edge, n_services, cfg, rng_mode=rng_mode
+            )
             self.buckets = bucket_arrivals(reqs, cfg.frame_ms, T)
+        else:
+            cols = scn.generate_arrivals_columns(
+                self.rng, n_edge, n_services, cfg, rng_mode=rng_mode
+            )
+            self.buckets = bucket_columns(cols, cfg.frame_ms, T)
         self._next = 0
 
     @property

@@ -27,9 +27,9 @@ pre-scenario-engine simulator (same RNG consumption order).
 Two RNG modes generate that traffic (``Scenario.rng_mode``, overridable per
 call):
 
-* ``"paper-default"`` — the legacy per-request Python loop: one exponential
-  gap, one thinning draw, one QoS draw at a time.  This is the default and
-  its RNG consumption order is frozen (a golden trace in
+* ``"paper-default"`` — the per-request Python loop: one exponential gap,
+  one thinning draw, one QoS draw at a time.  This is the default and its
+  RNG consumption order is frozen (a golden trace in
   ``tests/test_arrival_gen.py`` guards it), so every historical result
   reproduces bit-for-bit.
 * ``"vectorized"`` — batched generation: exponential inter-arrival gaps,
@@ -38,9 +38,12 @@ call):
   scale.  It is *distributionally identical* to the per-request loop (same
   thinned-Poisson process, same QoS/size laws — property-tested) and
   deterministic given the seed, but it consumes the RNG in a different
-  order, so it is strictly opt-in.  The vectorized trace is also available
-  columnar (:class:`RequestColumns`) so the fleet's grid builder never
-  touches per-request Python objects.
+  order, so it is strictly opt-in.
+
+Both modes write the trace into columns (:class:`RequestColumns`, from
+:meth:`Scenario.generate_arrivals_columns`), so the fleet's grid builder
+never touches per-request Python objects; :meth:`Scenario.generate_arrivals`
+wraps the same columns into :class:`Request` objects for ``simulate()``.
 
 Registry usage::
 
@@ -116,7 +119,7 @@ class Request:
 class RequestColumns:
     """Columnar arrival trace — the struct-of-arrays twin of ``List[Request]``.
 
-    The vectorized generator emits this so the fleet's grid builder
+    The arrival generators emit this so the fleet's grid builder
     (``repro.core.frames._build_frame_batch``) can fill whole frames with
     array slices instead of per-request Python attribute reads.  Arrays are
     parallel, sorted by ``arrival_ms``; float columns stay float64 (the RNG's
@@ -342,71 +345,106 @@ class Scenario:
     ) -> List[Request]:
         """Draw the full request trace for one replication.
 
-        ``rng_mode=None`` defers to :attr:`rng_mode`.  In ``"paper-default"``
-        mode, per edge: a thinned Poisson process against ``rate_bound``,
-        one request at a time.  When the instantaneous rate equals the bound
-        (constant-rate scenarios) the acceptance draw is skipped, which
-        keeps ``paper-default`` bit-identical to the legacy inline
-        generator.  ``"vectorized"`` draws the same process in numpy batches
-        (different RNG consumption, same distribution).  Requests come back
-        sorted by arrival either way.
+        ``rng_mode=None`` defers to :attr:`rng_mode`.  The trace is exactly
+        :meth:`generate_arrivals_columns`' for the same generator state,
+        wrapped into :class:`Request` objects: sorted by arrival, rids in
+        that order.
         """
-        mode = _resolve_rng_mode(self.rng_mode if rng_mode is None else rng_mode)
-        if mode == "vectorized":
-            return self.generate_arrivals_columns(
-                rng, n_edge, n_services, cfg
-            ).to_requests()
-        reqs: List[Request] = []
-        rid = 0
-        for e in range(n_edge):
-            rmax = float(self.rate_bound(e, cfg))
-            if rmax <= 0.0:
-                continue
-            t = 0.0
-            while t < cfg.horizon_ms:
-                t += rng.exponential(1000.0 / rmax)
-                if t >= cfg.horizon_ms:
-                    break
-                r_t = float(self.rate(e, t, cfg))
-                if r_t < rmax and rng.random() >= r_t / rmax:
-                    continue  # thinned away
-                service = int(rng.integers(0, n_services))
-                a, c = self.draw_qos(rng, cfg)
-                reqs.append(
-                    Request(
-                        rid=rid,
-                        arrival_ms=t,
-                        cover=e,
-                        service=service,
-                        A=a,
-                        C=c,
-                        size_bytes=float(rng.uniform(cfg.req_size_lo, cfg.req_size_hi)),
-                    )
-                )
-                rid += 1
-        reqs.sort(key=lambda r: r.arrival_ms)
-        for i, r in enumerate(reqs):  # rids in arrival order, like the testbed
-            r.rid = i
-        return reqs
+        return self.generate_arrivals_columns(
+            rng, n_edge, n_services, cfg, rng_mode=rng_mode
+        ).to_requests()
 
     def generate_arrivals_columns(
-        self, rng: np.random.Generator, n_edge: int, n_services: int, cfg
+        self,
+        rng: np.random.Generator,
+        n_edge: int,
+        n_services: int,
+        cfg,
+        rng_mode: Optional[str] = None,
     ) -> RequestColumns:
-        """Vectorized trace as :class:`RequestColumns` (the fleet's format).
+        """The replication's trace as :class:`RequestColumns` (the fleet's
+        format), stable-sorted by arrival (ties keep per-edge emission
+        order).
 
-        Edges draw sequentially from the shared ``rng`` — each edge drains
-        its chunked thinned-Poisson process (:func:`iter_edge_arrival_chunks`)
-        to the horizon — then the merged trace is stable-sorted by arrival.
-        ``generate_arrivals(rng_mode="vectorized")`` wraps exactly these
-        columns into :class:`Request` objects, so the two views of one seed
-        are the same trace.
+        ``rng_mode=None`` defers to :attr:`rng_mode`.  In ``"paper-default"``
+        mode, per edge: a thinned Poisson process against ``rate_bound``,
+        one request at a time (:meth:`_paper_default_columns`).  In
+        ``"vectorized"`` mode edges draw sequentially from the shared
+        ``rng``, each draining its chunked thinned-Poisson process
+        (:func:`iter_edge_arrival_chunks`) to the horizon: the same process
+        and laws, a different RNG consumption order.
         """
+        mode = _resolve_rng_mode(self.rng_mode if rng_mode is None else rng_mode)
+        if mode == "paper-default":
+            return self._paper_default_columns(rng, n_edge, n_services, cfg)
         parts: List[RequestColumns] = []
         for e in range(n_edge):
             parts.extend(
                 edge_arrival_columns(self, rng, e, n_services, cfg, cfg.horizon_ms)
             )
         return RequestColumns.concatenate(parts).sorted_by_arrival()
+
+    def _paper_default_columns(
+        self, rng: np.random.Generator, n_edge: int, n_services: int, cfg
+    ) -> RequestColumns:
+        """The frozen per-request draw loop, written into columns.
+
+        Per edge and per candidate arrival, in this order: the exponential
+        gap, the horizon test, the thinning uniform (only where the rate is
+        below ``rate_bound``), the service, :meth:`draw_qos`, the payload.
+        That order is the golden trace's (``tests/test_arrival_gen.py``) and
+        the benchmark reference's.  Two draws use cheaper calls that NumPy
+        computes to the same bits from the same generator state:
+        ``scale * standard_exponential()`` is ``exponential(scale)`` and
+        ``lo + (hi - lo) * random()`` is ``uniform(lo, hi)``.  A scenario
+        that keeps the base :meth:`rate` has a constant rate, so it is read
+        once per edge instead of once per request.
+        """
+        horizon = cfg.horizon_ms
+        size_lo = float(cfg.req_size_lo)
+        size_span = float(cfg.req_size_hi) - size_lo
+        gap = rng.standard_exponential
+        uniform = rng.random
+        integers = rng.integers
+        draw_qos = self.draw_qos
+        varying = type(self).rate is not Scenario.rate
+        ts: List[float] = []
+        svc: List[int] = []
+        a_col: List[float] = []
+        c_col: List[float] = []
+        size: List[float] = []
+        per_edge = np.zeros(n_edge, np.int64)
+        for e in range(n_edge):
+            rmax = float(self.rate_bound(e, cfg))
+            if rmax <= 0.0:
+                continue
+            scale = 1000.0 / rmax
+            r_t = None if varying else float(self.rate(e, 0.0, cfg))
+            n0 = len(ts)
+            t = 0.0
+            while t < horizon:
+                t += scale * gap()
+                if t >= horizon:
+                    break
+                if varying:
+                    r_t = float(self.rate(e, t, cfg))
+                if r_t < rmax and uniform() >= r_t / rmax:
+                    continue  # thinned away
+                svc.append(int(integers(0, n_services)))
+                a, c = draw_qos(rng, cfg)
+                ts.append(t)
+                a_col.append(a)
+                c_col.append(c)
+                size.append(size_lo + size_span * uniform())
+            per_edge[e] = len(ts) - n0
+        return RequestColumns(
+            arrival_ms=np.array(ts, np.float64),
+            cover=np.repeat(np.arange(n_edge, dtype=np.int64), per_edge),
+            service=np.array(svc, np.int64),
+            A=np.array(a_col, np.float64),
+            C=np.array(c_col, np.float64),
+            size_bytes=np.array(size, np.float64),
+        ).sorted_by_arrival()
 
 
 def edge_arrival_columns(
@@ -546,13 +584,13 @@ def bucket_columns(
     """:func:`bucket_arrivals` for a columnar trace — per-frame column views.
 
     ``cols`` must be sorted by arrival (the generator's contract), so each
-    frame is a contiguous slice found by ``searchsorted``; anything at or
-    past the last boundary clamps into the final frame, exactly like the
-    per-request bucketing.
+    frame is a contiguous slice found by ``searchsorted`` on the frame
+    indices.  The index is the per-request one, ``arrival_ms // frame_ms``
+    clamped to the last frame (NumPy's float floor division is Python's),
+    so both layouts of one trace bucket identically.
     """
-    edges = np.searchsorted(
-        cols.arrival_ms, np.arange(1, n_frames) * frame_ms, side="left"
-    )
+    frame = np.minimum(np.floor_divide(cols.arrival_ms, frame_ms), n_frames - 1)
+    edges = np.searchsorted(frame, np.arange(1, n_frames), side="left")
     bounds = np.concatenate([[0], edges, [len(cols)]])
     return [
         cols.slice(int(bounds[i]), int(bounds[i + 1])) for i in range(n_frames)

@@ -901,7 +901,9 @@ class FleetResult:
     #: are derived from these same spans, so the two views can never
     #: disagree — plus its counters, which are integer counts, not seconds:
     #: keys ending ``_bytes`` (``fleet/h2d_bytes``,
-    #: ``fleet/accounting_h2d_bytes``) and ``/rows`` (``fleet/rows``)
+    #: ``fleet/accounting_h2d_bytes``), ``/rows`` (``fleet/rows``) and
+    #: ``fleet/columnar_frames`` (frame buckets the sources handed over as
+    #: :class:`~repro.core.scenarios.RequestColumns`)
     timings: Optional[Dict[str, Union[float, int]]] = None
     #: per-(rep, frame) metric stream (``metrics=True`` only; None otherwise)
     metrics: Optional[MetricsResult] = None
@@ -1108,6 +1110,12 @@ def _pad_reps(tree, pad_r: int):
         return xp.concatenate([x, xp.repeat(x[:1], pad_r, axis=0)])
 
     return jax.tree.map(pad, tree)
+
+
+def _n_columnar(buckets) -> int:
+    """How many of a source's frame buckets are :class:`RequestColumns`
+    (the ``fleet/columnar_frames`` counter)."""
+    return sum(isinstance(b, RequestColumns) for b in buckets)
 
 
 class _WindowPipeline:
@@ -1354,10 +1362,11 @@ def simulate_fleet(
 
     ``rng_mode`` (``None`` defers to ``scenario.rng_mode``) selects the
     arrival generator: ``"paper-default"`` keeps the frozen per-request
-    draw order, ``"vectorized"`` generates in numpy batches and keeps the
-    whole trace columnar (:class:`~repro.core.scenarios.RequestColumns`) so
-    the grid builder fills frames from array slices — ~10x faster host
-    generation, different (equally distributed, seed-deterministic) traces.
+    draw order, ``"vectorized"`` generates in numpy batches — ~10x faster
+    host generation, different (equally distributed, seed-deterministic)
+    traces.  Either way a materialized trace stays columnar
+    (:class:`~repro.core.scenarios.RequestColumns`) so the grid builder
+    fills frames from array slices.
 
     ``policy`` names a registered :class:`~repro.core.policies.Policy`; a
     ``needs_key`` policy (``random``) receives one PRNG key per
@@ -1579,6 +1588,7 @@ def simulate_fleet(
                                 for r in bucket
                             ]
                     i += 1
+            sw.count("fleet/columnar_frames", _n_columnar(frames))
         with sw.span("fleet/grid_build", CAT_BUILD, t0=t0):
             # per-frame budgets are replication-independent: one *batched*
             # capacity-stream call per window, reused across the R reps
@@ -1778,6 +1788,7 @@ def _simulate_fleet_host(
     with sw.span("fleet/arrivals", CAT_GEN):
         for src in sources:
             fleet_frames.extend(src.take(T))
+        sw.count("fleet/columnar_frames", _n_columnar(fleet_frames))
     raw_insts = []
     n_real = np.array([len(b) for b in fleet_frames], np.int32)
     tq_flat = np.zeros((len(fleet_frames), n_pad), np.float32)
@@ -2272,6 +2283,7 @@ def _simulate_fleet_hier(
         for rep, src in enumerate(sources):
             with sw.span("fleet/arrivals", CAT_GEN, t0=t0, rep=rep):
                 buckets = src.take(t1)
+                sw.count("fleet/columnar_frames", _n_columnar(buckets))
             rep_infos: List[Optional[dict]] = []
             for k, bucket in enumerate(buckets):
                 frame_start = (t0 + k) * cfg.frame_ms

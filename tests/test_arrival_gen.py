@@ -8,6 +8,7 @@ different order — these tests pin (a) that the default mode's traces can
 never drift (any RNG refactor that changes them fails the golden test) and
 (b) that the vectorized mode draws the same thinned-Poisson process and the
 same QoS/size laws, just batched."""
+import dataclasses
 import math
 
 import numpy as np
@@ -18,6 +19,7 @@ pytest.importorskip("jax")
 from repro.core import (  # noqa: E402
     RNG_MODES,
     RequestColumns,
+    Scenario,
     SimConfig,
     bucket_arrivals,
     bucket_columns,
@@ -158,7 +160,9 @@ def test_columns_and_requests_are_one_trace(scenario):
     scn = get_scenario(scenario)
     c = cfg()
     reqs = scn.generate_arrivals(np.random.default_rng(9), 4, 3, c, rng_mode="vectorized")
-    cols = scn.generate_arrivals_columns(np.random.default_rng(9), 4, 3, c)
+    cols = scn.generate_arrivals_columns(
+        np.random.default_rng(9), 4, 3, c, rng_mode="vectorized"
+    )
     assert len(cols) == len(reqs)
     assert [req_tuple(r) for r in cols.to_requests()] == [req_tuple(r) for r in reqs]
 
@@ -166,7 +170,9 @@ def test_columns_and_requests_are_one_trace(scenario):
 def test_bucket_columns_matches_bucket_arrivals():
     scn = get_scenario("flash-crowd")
     c = cfg()
-    cols = scn.generate_arrivals_columns(np.random.default_rng(3), 4, 3, c)
+    cols = scn.generate_arrivals_columns(
+        np.random.default_rng(3), 4, 3, c, rng_mode="vectorized"
+    )
     n_frames = int(np.ceil(c.horizon_ms / c.frame_ms))
     by_req = bucket_arrivals(cols.to_requests(), c.frame_ms, n_frames)
     by_col = bucket_columns(cols, c.frame_ms, n_frames)
@@ -185,6 +191,165 @@ def test_stream_trace_columns_matches_vectorized_stream():
         via_stream = stream_trace(scenario, 21, 4, 3, c, rng_mode="vectorized")
         via_cols = stream_trace_columns(scenario, 21, 4, 3, c).to_requests()
         assert [req_tuple(r) for r in via_stream] == [req_tuple(r) for r in via_cols]
+
+
+# ---------------------------------------------------------------------------
+# Paper-default mode: the scalar draw loop written into columns
+# ---------------------------------------------------------------------------
+
+
+def _scalar_trace(scn, rng, n_edge, n_services, c):
+    """The paper-default per-request loop as it was before it wrote columns,
+    kept as the reference: ``(arrival_ms, cover, service, A, C, size_bytes)``
+    rows in emission order, then stable-sorted by arrival."""
+    rows = []
+    for e in range(n_edge):
+        rmax = float(scn.rate_bound(e, c))
+        if rmax <= 0.0:
+            continue
+        t = 0.0
+        while t < c.horizon_ms:
+            t += rng.exponential(1000.0 / rmax)
+            if t >= c.horizon_ms:
+                break
+            r_t = float(scn.rate(e, t, c))
+            if r_t < rmax and rng.random() >= r_t / rmax:
+                continue
+            service = int(rng.integers(0, n_services))
+            a, d = scn.draw_qos(rng, c)
+            size = float(rng.uniform(c.req_size_lo, c.req_size_hi))
+            rows.append((t, e, service, a, d, size))
+    rows.sort(key=lambda r: r[0])
+    return rows
+
+
+@dataclasses.dataclass(frozen=True)
+class _CoinQos(Scenario):
+    """Overrides ``draw_qos`` with a variable number of draws per request
+    and NumPy scalars in its answer."""
+
+    name: str = "local-coin-qos"
+
+    def draw_qos(self, rng, cfg):
+        if rng.random() < 0.5:
+            return np.float32(rng.normal(60.0, 5.0)), 900.0
+        return np.float64(rng.uniform(20.0, 40.0)), float(rng.integers(500, 5000))
+
+
+@dataclasses.dataclass(frozen=True)
+class _Pulsed(Scenario):
+    """A time-varying rate with only the scalar hook: full rate in even
+    seconds, a fifth of it in odd ones, so most candidates are thinned."""
+
+    name: str = "local-pulsed"
+
+    def rate(self, edge, t_ms, cfg):
+        on = int(t_ms // 1000.0) % 2 == 0
+        return cfg.arrival_rate_per_s * (1.0 if on else 0.2)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Envelope(Scenario):
+    """The base constant rate under a looser bound: every candidate takes
+    the thinning draw against a rate read once per edge."""
+
+    name: str = "local-envelope"
+
+    def rate_bound(self, edge, cfg):
+        return cfg.arrival_rate_per_s * (2.0 + edge)
+
+
+_LOCAL_SCENARIOS = {s.name: s for s in (_CoinQos(), _Pulsed(), _Envelope())}
+PAPER_DEFAULT_CASES = list_scenarios() + sorted(_LOCAL_SCENARIOS)
+
+
+def _paper_default_case(name):
+    """The scenario and a config that keeps its trace a few hundred rows:
+    the horizon shrinks for scenarios whose own rates are city-scale."""
+    scn = _LOCAL_SCENARIOS.get(name) or get_scenario(name)
+    c = cfg()
+    bound = max(float(scn.rate_bound(e, c)) for e in range(4))
+    if bound * c.horizon_ms / 1000.0 > 200.0:
+        c = cfg(horizon_ms=200_000.0 / bound)
+    return scn, c
+
+
+@pytest.mark.parametrize("name", PAPER_DEFAULT_CASES)
+def test_paper_default_columns_match_the_scalar_loop(name):
+    """Every draw, in the same order: the columns equal the reference loop
+    column for column, and both leave the generator in the same state."""
+    scn, c = _paper_default_case(name)
+    rng, ref_rng = np.random.default_rng(31), np.random.default_rng(31)
+    cols = scn.generate_arrivals_columns(rng, 4, 3, c, rng_mode="paper-default")
+    rows = _scalar_trace(scn, ref_rng, 4, 3, c)
+    assert len(rows) > 20
+    want = [np.array(col) for col in zip(*rows)]
+    got = [cols.arrival_ms, cols.cover, cols.service, cols.A, cols.C, cols.size_bytes]
+    for g, w, dtype in zip(got, want, [np.float64, np.int64, np.int64] + [np.float64] * 3):
+        assert g.dtype == dtype
+        np.testing.assert_array_equal(g, w.astype(dtype))
+    assert rng.bit_generator.state == ref_rng.bit_generator.state
+
+
+@pytest.mark.parametrize("name", PAPER_DEFAULT_CASES)
+def test_paper_default_requests_are_the_columns(name):
+    """``generate_arrivals`` is ``to_requests()`` of the columnar trace, and
+    both are the reference loop's rows with rids in arrival order."""
+    scn, c = _paper_default_case(name)
+    cols = scn.generate_arrivals_columns(
+        np.random.default_rng(17), 4, 3, c, rng_mode="paper-default"
+    )
+    reqs = scn.generate_arrivals(
+        np.random.default_rng(17), 4, 3, c, rng_mode="paper-default"
+    )
+    rows = _scalar_trace(scn, np.random.default_rng(17), 4, 3, c)
+    assert [req_tuple(r) for r in cols.to_requests()] == [req_tuple(r) for r in reqs]
+    assert [req_tuple(r) for r in reqs] == [(i,) + row for i, row in enumerate(rows)]
+
+
+N_DRAWS = 100_000
+
+
+@pytest.mark.parametrize(
+    "lo, hi", [(2e4, 1.2e5), (0.0, 1.0), (-3.5, 7.25), (1e-3, 1e9)]
+)
+def test_uniform_as_scaled_random_is_bit_identical(lo, hi):
+    """``lo + (hi - lo) * random()`` is ``uniform(lo, hi)`` on the same
+    generator state (NumPy computes ``low + range * next_double``)."""
+    a, b = np.random.default_rng(2026), np.random.default_rng(2026)
+    want = [a.uniform(lo, hi) for _ in range(N_DRAWS)]
+    got = [lo + (hi - lo) * b.random() for _ in range(N_DRAWS)]
+    assert got == want
+
+
+@pytest.mark.parametrize("scale", [1000.0 / 2.7777777777777777, 1000.0 / 3.0, 1.0, 1e-3])
+def test_exponential_as_scaled_standard_is_bit_identical(scale):
+    """``scale * standard_exponential()`` is ``exponential(scale)`` on the
+    same generator state."""
+    a, b = np.random.default_rng(7), np.random.default_rng(7)
+    want = [a.exponential(scale) for _ in range(N_DRAWS)]
+    got = [scale * b.standard_exponential() for _ in range(N_DRAWS)]
+    assert got == want
+
+
+@pytest.mark.parametrize("frame_ms", [0.1, 3000.0])
+def test_bucket_columns_uses_the_per_request_frame_index(frame_ms):
+    """Arrivals on and next to every frame boundary land where
+    ``bucket_arrivals`` puts them: the index is ``arrival_ms // frame_ms``,
+    which for ``frame_ms=0.1`` puts ``1.0`` in frame 9, below ``10 * 0.1``."""
+    n_frames = 40
+    b = np.arange(1, n_frames + 2) * frame_ms
+    t = np.sort(np.concatenate([np.nextafter(b, 0.0), b, np.nextafter(b, np.inf)]))
+    n = t.size
+    cols = RequestColumns(
+        arrival_ms=t, cover=np.zeros(n, np.int64), service=np.arange(n, dtype=np.int64),
+        A=np.zeros(n), C=np.zeros(n), size_bytes=np.zeros(n),
+    )
+    by_req = bucket_arrivals(cols.to_requests(), frame_ms, n_frames)
+    by_col = bucket_columns(cols, frame_ms, n_frames)
+    assert [[r.service for r in br] for br in by_req] == [
+        bc.service.tolist() for bc in by_col
+    ]
 
 
 # ---------------------------------------------------------------------------

@@ -97,3 +97,93 @@ def test_bandwidth_ema_tracks_channel():
     assert len(est) > 3
     assert abs(est[-1] - 900.0) / 900.0 < 0.35, est[-5:]
     assert abs(est[-1] - 900.0) < abs(est[0] - 900.0)
+
+
+# ---------------------------------------------------------------------------
+# Fleet: columnar paper-default buckets against Request buckets
+# ---------------------------------------------------------------------------
+
+import dataclasses  # noqa: E402
+
+import pytest  # noqa: E402
+
+from repro.core import (  # noqa: E402
+    BurstyLossLink,
+    EngineOptions,
+    ImpairmentConfig,
+    IntermittentLink,
+    Scenario,
+    demo_cluster_spec,
+    simulate_fleet,
+)
+
+
+@dataclasses.dataclass(frozen=True)
+class _RequestBuckets(Scenario):
+    """The paper workload handed to the fleet as ``Request`` buckets: a
+    scenario that overrides ``generate_arrivals`` keeps its objects, and
+    these are ``to_requests()`` of the very columns the plain scenario
+    hands over for the same generator state."""
+
+    name: str = "paper-default-requests"
+
+    def generate_arrivals(self, rng, n_edge, n_services, cfg, rng_mode=None):
+        return super().generate_arrivals(rng, n_edge, n_services, cfg, rng_mode=rng_mode)
+
+
+FLEET_CASES = {
+    "dense-gus": (dict(), dict(policy="gus"), EngineOptions(metrics=True)),
+    "host-policy": (dict(), dict(policy="lp-bound"), EngineOptions(metrics=True)),
+    "hierarchical": (
+        dict(), dict(policy="gus"),
+        EngineOptions(scheduler="hierarchical", metrics=True),
+    ),
+    "mobility": (dict(move_prob=0.3), dict(policy="gus"), EngineOptions(metrics=True)),
+    "impairments": (
+        dict(impairments=ImpairmentConfig(
+            enabled=True, link_profiles=(IntermittentLink(), BurstyLossLink()), seed=7,
+        )),
+        dict(policy="gus"), EngineOptions(metrics=True),
+    ),
+}
+
+
+def _fleet_pair(case, n_rep=3, seed=11):
+    sim_kw, call_kw, opts = FLEET_CASES[case]
+    c = SimConfig(horizon_ms=12_000.0, arrival_rate_per_s=3.0, delay_req_ms=6000.0,
+                  **sim_kw)
+    spec = demo_cluster_spec()
+    runs = [
+        simulate_fleet(spec, c, scenario=scn, n_rep=n_rep, seed=seed, options=opts,
+                       **call_kw)
+        for scn in (Scenario(), _RequestBuckets())
+    ]
+    return c, runs
+
+
+@pytest.mark.parametrize("case", sorted(FLEET_CASES))
+def test_fleet_columnar_buckets_match_request_buckets(case):
+    """The same draws give the same fleet, whether the sources hand the
+    frames over as columns (the plain scenario) or as ``Request`` lists."""
+    _, (cols, reqs) = _fleet_pair(case)
+    assert cols.n_requests > 0
+    assert cols.as_dict() == reqs.as_dict()
+    np.testing.assert_array_equal(cols.satisfied_per_rep, reqs.satisfied_per_rep)
+    np.testing.assert_array_equal(cols.mean_us_per_rep, reqs.mean_us_per_rep)
+    assert (cols.final_backlog_per_rep is None) == (reqs.final_backlog_per_rep is None)
+    if cols.final_backlog_per_rep is not None:
+        np.testing.assert_array_equal(cols.final_backlog_per_rep, reqs.final_backlog_per_rep)
+    assert cols.mean_compute_inflation == reqs.mean_compute_inflation
+    assert cols.metrics.data.keys() == reqs.metrics.data.keys()
+    for key, arr in cols.metrics.data.items():
+        np.testing.assert_array_equal(arr, reqs.metrics.data[key], err_msg=key)
+
+
+@pytest.mark.parametrize("case", ["dense-gus", "host-policy", "hierarchical"])
+def test_fleet_columnar_frames_counter(case):
+    """``fleet/columnar_frames`` counts every frame of a paper-default
+    study (n_rep x frames), and none where the buckets are Request lists."""
+    c, (cols, reqs) = _fleet_pair(case)
+    T = int(np.ceil(c.horizon_ms / c.frame_ms))
+    assert cols.timings["fleet/columnar_frames"] == cols.n_rep * T
+    assert reqs.timings["fleet/columnar_frames"] == 0
